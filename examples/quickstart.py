@@ -33,7 +33,7 @@ handle = engine.register("ffn_up", w, w_spec=QuantSpec(bits=3),
 
 out_sim, report = engine.gemv(handle, a, backend=backends.SIM)
 out_jnp = engine.gemv(handle, a, backend=backends.JNP)
-out_pal = engine.gemv(handle, a[None], backend=backends.PALLAS)[0]
+out_pal = engine.gemv(handle, a[None], backend=backends.PALLAS_INTERPRET)[0]
 
 print("=== correctness (three backends) ===")
 print("PUD sim vs jnp oracle  max|Δ|:",

@@ -102,16 +102,28 @@ class JnpBackend(Backend):
         return bitplane_gemv_bitserial(aq, handle.weights)
 
 
+def require_tpu(what: str) -> None:
+    """Raise unless JAX runs on a TPU: a compiled Pallas kernel never
+    quietly turns into its interpreter, so a CPU run cannot pass for a
+    kernel run."""
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"{what} compiles the Pallas kernels for a TPU, but JAX runs on "
+            f"{jax.default_backend()!r}; name PALLAS_INTERPRET (the kernel "
+            f"body in interpret mode) to run them here")
+
+
 class PallasBackend(Backend):
-    """The TPU kernel (kernels/bitplane_gemv); interpret-mode kernel body
-    off-TPU — a single source of truth for gemv() and serving linear()."""
+    """The TPU kernel (kernels/bitplane_gemv) — a single source of truth
+    for gemv() and serving linear(). Raises off-TPU: CPU callers name
+    `PALLAS_INTERPRET`."""
 
     name = "pallas"
 
     @property
     def kernel_impl(self) -> str:
-        return "pallas" if jax.default_backend() == "tpu" else \
-            "pallas_interpret"
+        require_tpu("the PALLAS backend")
+        return "pallas"
 
     def gemv(self, engine, handle, a, *, fidelity: str = "code", **opts):
         from ..kernels.bitplane_gemv import ops as bp_ops
@@ -141,9 +153,8 @@ class PallasBackend(Backend):
 
 
 class PallasInterpretBackend(PallasBackend):
-    """Interpret-mode Pallas forced regardless of the jax backend — keeps
-    the pre-registry `impl="pallas_interpret"` call sites working (the
-    kernel impl string doubled as a mode before the Backend refactor)."""
+    """The Pallas kernel bodies in interpret mode, on any jax backend —
+    how CPU callers (tests, examples) run the kernels."""
 
     name = "pallas_interpret"
 

@@ -483,14 +483,15 @@ class GemvProgram:
     def run_kernel(self, activations: Sequence[jax.Array],
                    fidelity: str = "code",
                    lane_mask: Optional[np.ndarray] = None,
-                   interpret: Optional[bool] = None) -> list:
+                   interpret: bool = False) -> list:
         """Execute one decode step as ONE fused Pallas launch walking the
         program's schedule — the jit-path twin of `run`. activations[l] is
         layer l's (B, N_l) lane batch (or (N_l,), promoted to B=1; B must
         equal `b_max` for a capacity program). Returns per-layer (B, M_l)
         outputs integer-identical to per-leaf `bitplane_gemv_bitserial`
         calls; masked lanes return zero rows, like `run(lane_mask=…)`.
-        `interpret=None` auto-selects interpret mode off-TPU."""
+        The compiled kernel needs a TPU; `interpret=True` runs the kernel
+        body anywhere."""
         import jax.numpy as jnp
         from ..kernels.bitplane_gemv import program as bp_program
         if len(activations) != self.layers:
@@ -516,8 +517,8 @@ class GemvProgram:
                 f"capacity program launches exactly b_max={self.b_max} "
                 f"lanes, got B={b}; mask idle lanes with lane_mask")
         lane_mask = _lane_mask_arg(lane_mask, b)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        if not interpret:
+            _backends.require_tpu("GemvProgram.run_kernel")
         plan = self.kernel_plan()
         if self._kernel_packed is None:
             # weights are static per program: pack the slot-major plane/
@@ -527,7 +528,7 @@ class GemvProgram:
         outs = bp_program.run_program(
             plan, tuple(h.weights for h in self.handles), tuple(xs),
             tuple(h.a_spec for h in self.handles), fidelity=fidelity,
-            interpret=bool(interpret), packed=self._kernel_packed)
+            interpret=interpret, packed=self._kernel_packed)
         if lane_mask is not None:
             keep = jnp.asarray(lane_mask)[:, None]
             outs = [jnp.where(keep, o, 0) for o in outs]

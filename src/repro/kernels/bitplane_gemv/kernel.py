@@ -25,8 +25,17 @@ count the benchmark trajectory records.
 Shared structure: `_unpack_words` expansion of every weight plane is hoisted
 out of the (i, k) accumulation loops — each plane is unpacked exactly once
 per tile regardless of fidelity. Both kernels accumulate across the
-reduction grid axis into the output block (grid = (m_tiles, n_tiles), out
-indexed by m only — revisited blocks persist in VMEM, initialized at n==0).
+reduction grid axis into the output block (grid = (row_tiles, m_tiles,
+n_tiles), out indexed by (row, m) — revisited blocks persist in VMEM,
+initialized at n==0). The activation-row axis is tiled (`row_block`) so a
+prefill's B·S rows never have to fit VMEM at once.
+
+Mosaic constraints the layout follows: the per-tile scales arrive as a
+(T, 1, M) array so their (1, bm) block spans the full second-minor dim, and
+the "code" dot runs bf16×bf16 with f32 accumulation — Mosaic has no int32
+matmul. That dot stays exact: codes ≤ 255 and 0/1 planes are exact in bf16,
+and a tile's partial sum ≤ bn·255 < 2^24 is exact in f32; plane sums then
+accumulate in int32.
 """
 from __future__ import annotations
 
@@ -35,12 +44,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 #: per-leaf pallas_call constructions (trace-time) — the contrast counter
 #: for the fused program path's one-launch-per-block assertion.
 LAUNCHES = 0
+
+
+#: activation rows per grid step: decode batches fit one block, prefill
+#: chunks tile (a multiple of the int8 sublane tile, 32)
+ROW_BLOCK = 256
 
 
 def _unpack_words(words: jax.Array, bn: int) -> jax.Array:
@@ -49,6 +62,36 @@ def _unpack_words(words: jax.Array, bn: int) -> jax.Array:
     shifts = jnp.arange(32, dtype=jnp.uint32)[None, :, None]
     bits = (words[:, None, :] >> shifts) & jnp.uint32(1)
     return bits.reshape(w * 32, bm)[:bn].astype(jnp.int8)
+
+
+def row_block(rows: int) -> int:
+    """Rows per grid step: all of them up to ROW_BLOCK, else ROW_BLOCK."""
+    return min(rows, ROW_BLOCK)
+
+
+def _pad_axis(x, mult, axis, value=0):
+    pad = (-x.shape[axis]) % mult
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths, constant_values=value)
+
+
+def code_dot(a_codes: jax.Array, plane: jax.Array) -> jax.Array:
+    """Exact integer (rows, bn)·(bn, bm) of codes against a 0/1 plane, as
+    a bf16 MXU dot with f32 accumulation (see the module docstring)."""
+    # Mosaic casts uint8 to a float only by way of int32
+    a = a_codes.astype(jnp.int32).astype(jnp.bfloat16)
+    d = jax.lax.dot(a, plane, preferred_element_type=jnp.float32)
+    return d.astype(jnp.int32)
+
+
+def activation_bits(a_codes: jax.Array, p: int) -> list:
+    """The p activation bit planes as int8 (widened to int32 first: Mosaic
+    cannot shift uint8)."""
+    a = a_codes.astype(jnp.int32)
+    return [((a >> k) & 1).astype(jnp.int8) for k in range(p)]
 
 
 def dots_per_tile(q: int, p: int, fidelity: str = "code") -> int:
@@ -63,7 +106,7 @@ def dots_per_tile(q: int, p: int, fidelity: str = "code") -> int:
 
 def _gemv_f_kernel(a_ref, planes_ref, scale_ref, out_ref, *, q: int,
                    zero: int, bn: int):
-    n_idx = pl.program_id(1)
+    n_idx = pl.program_id(2)
 
     @pl.when(n_idx == 0)
     def _init():
@@ -78,7 +121,7 @@ def _gemv_f_kernel(a_ref, planes_ref, scale_ref, out_ref, *, q: int,
         acc += (2.0 ** i) * jax.lax.dot(
             a_blk, planes[i], precision=jax.lax.Precision.HIGHEST)
     corr = acc - zero * jnp.sum(a_blk, axis=-1, keepdims=True)
-    out_ref[...] += corr * scale_ref[...]                # (1, bm) broadcast
+    out_ref[...] += corr * scale_ref[0]                  # (1, bm) broadcast
 
 
 def gemv_f_pallas(a, planes, scale_tiles, *, q: int, zero: int,
@@ -87,24 +130,37 @@ def gemv_f_pallas(a, planes, scale_tiles, *, q: int, zero: int,
 
     N must divide by bn (pad upstream: a with 0), M by bm.
     """
-    b, n = a.shape
+    b = a.shape[0]
+    br = row_block(b)
+    a = _pad_axis(a, br, 0)
+    out = _leaf_call(
+        functools.partial(_gemv_f_kernel, q=q, zero=zero, bn=bn),
+        a, planes, scale_tiles, q=q, bn=bn, bm=bm, br=br,
+        interpret=interpret)
+    return out[:b]
+
+
+def _leaf_call(body, a, planes, scale_tiles, *, q: int, bn: int, bm: int,
+               br: int, interpret: bool):
+    """The per-leaf launch shared by both kernels: grid (row, m, n) tiles,
+    accumulating over the innermost reduction axis."""
+    rows, n = a.shape
     m = planes.shape[-1]
     wpb = bn // 32  # packed words per reduction block
-    grid = (m // bm, n // bn)
     return pl.pallas_call(
-        functools.partial(_gemv_f_kernel, q=q, zero=zero, bn=bn),
-        grid=grid,
+        body,
+        grid=(rows // br, m // bm, n // bn),
         in_specs=[
-            pl.BlockSpec((b, bn), lambda mi, ni: (0, ni)),
-            pl.BlockSpec((q, wpb, bm), lambda mi, ni: (0, ni, mi)),
-            pl.BlockSpec((1, bm), lambda mi, ni: (ni, mi)),
+            pl.BlockSpec((br, bn), lambda ri, mi, ni: (ri, ni)),
+            pl.BlockSpec((q, wpb, bm), lambda ri, mi, ni: (0, ni, mi)),
+            pl.BlockSpec((1, 1, bm), lambda ri, mi, ni: (ni, 0, mi)),
         ],
-        out_specs=pl.BlockSpec((b, bm), lambda mi, ni: (0, mi)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        out_specs=pl.BlockSpec((br, bm), lambda ri, mi, ni: (ri, mi)),
+        out_shape=jax.ShapeDtypeStruct((rows, m), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, planes, scale_tiles)
+    )(a, planes, scale_tiles.reshape(scale_tiles.shape[0], 1, m))
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +172,7 @@ def gemv_f_pallas(a, planes, scale_tiles, *, q: int, zero: int,
 
 def _gemv_bs_kernel(a_ref, planes_ref, scale_ref, out_ref, *, q: int, p: int,
                     z_a: int, z_w: int, bn: int, fidelity: str):
-    n_idx = pl.program_id(1)
+    n_idx = pl.program_id(2)
 
     @pl.when(n_idx == 0)
     def _init():
@@ -134,13 +190,11 @@ def _gemv_bs_kernel(a_ref, planes_ref, scale_ref, out_ref, *, q: int, p: int,
     acc = jnp.zeros((b, bm), jnp.int32)
     if fidelity == "code":
         # Σ_k 2^k a^(k) = a_codes ⇒ one dot per weight plane (exact).
-        a_int = a_codes.astype(jnp.int32)
         for i in range(q):
-            acc += (1 << i) * jax.lax.dot(
-                a_int, planes[i].astype(jnp.int32),
-                preferred_element_type=jnp.int32)
+            acc += (1 << i) * code_dot(a_codes,
+                                       planes[i].astype(jnp.bfloat16))
     else:  # "bitserial": the fully decomposed q·p-dot schedule (oracle)
-        a_bits = [((a_codes >> k) & 1).astype(jnp.int8) for k in range(p)]
+        a_bits = activation_bits(a_codes, p)
         for i in range(q):
             for k in range(p):
                 # a^(k) AND W^(i), popcount-accumulated: an int MXU matmul.
@@ -149,7 +203,7 @@ def _gemv_bs_kernel(a_ref, planes_ref, scale_ref, out_ref, *, q: int, p: int,
                 acc += (1 << (i + k)) * partial
     sum_a = jnp.sum(a_codes.astype(jnp.int32), axis=-1, keepdims=True)
     corr = acc - z_a * col_sum - z_w * sum_a + bn * z_a * z_w
-    out_ref[...] += corr.astype(jnp.float32) * scale_ref[...]
+    out_ref[...] += corr.astype(jnp.float32) * scale_ref[0]
 
 
 def gemv_bs_pallas(a_codes, planes, scale_tiles, *, q: int, p: int,
@@ -162,22 +216,12 @@ def gemv_bs_pallas(a_codes, planes, scale_tiles, *, q: int, p: int,
             f"fidelity must be 'code' or 'bitserial', got {fidelity!r} "
             f"(a_codes shape {tuple(a_codes.shape)})")
     LAUNCHES += 1
-    b, n = a_codes.shape
-    m = planes.shape[-1]
-    wpb = bn // 32
-    grid = (m // bm, n // bn)
-    return pl.pallas_call(
+    b = a_codes.shape[0]
+    br = row_block(b)
+    a_codes = _pad_axis(a_codes, br, 0, value=z_a)
+    out = _leaf_call(
         functools.partial(_gemv_bs_kernel, q=q, p=p, z_a=z_a, z_w=z_w,
                           bn=bn, fidelity=fidelity),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, bn), lambda mi, ni: (0, ni)),
-            pl.BlockSpec((q, wpb, bm), lambda mi, ni: (0, ni, mi)),
-            pl.BlockSpec((1, bm), lambda mi, ni: (ni, mi)),
-        ],
-        out_specs=pl.BlockSpec((b, bm), lambda mi, ni: (0, mi)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(a_codes, planes, scale_tiles)
+        a_codes, planes, scale_tiles, q=q, bn=bn, bm=bm, br=br,
+        interpret=interpret)
+    return out[:b]

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from ...core.bitplane import BitplaneWeights
 from ...core.quant import QuantSpec, quantize_activations
 from . import kernel, ref
+from .kernel import _pad_axis
 
 DEFAULT_BN = 512   # reduction-dim block (multiple of 32-bit packing)
 DEFAULT_BM = 256   # output-dim block (multiple of 128 lanes)
@@ -42,15 +43,6 @@ def _pick_blocks(n: int, m: int, bn: Optional[int], bm: Optional[int],
     # dim must never shrink the block into a misaligned Pallas grid
     bm = max(128, (bm // 128) * 128)
     return bn, bm
-
-
-def _pad_axis(x, mult, axis, value=0):
-    pad = (-x.shape[axis]) % mult
-    if not pad:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
 
 
 def _expand_scales(bw: BitplaneWeights, bn: int, n_pad: int) -> jax.Array:

@@ -20,13 +20,18 @@ the program-wide (BN, BM) envelope with *exactness-preserving* values —
 so the padded rows cancel algebraically: the extra `−z_w·(BN−bn)·z_a` from
 `sum_a` is exactly offset by the extra `+(BN−bn)·z_a·z_w`, the extra plane
 rows are zero so `acc` and `col_sum` are untouched, and every operation is
-int32 — the fused kernel is integer-identical (not just close) to the
-per-leaf path. Fully-padded grid steps (a layer with fewer reduction tiles
-than the envelope) carry z_a = z_w = 0, zero codes and zero scales and
-contribute exactly 0.0. Mixed weight/activation precisions ride the same
-trick: the plane loop runs to the envelope q_max with zero-padded planes,
-and the bitserial path's code loop to p_max — codes < 2^p_l have zero high
-bits, so the extra dots are exact zeros.
+integer-exact — the fused kernel is integer-identical (not just close) to
+the per-leaf path. Fully-padded grid steps (a layer with fewer reduction
+tiles than the envelope) carry zero scales, so whatever their finite
+integer correction is, they contribute exactly 0.0. Mixed weight/activation
+precisions ride the same trick: the plane loop runs to the envelope q_max
+with zero-padded planes, and the bitserial path's code loop to p_max —
+codes < 2^p_l have zero high bits, so the extra dots are exact zeros.
+
+Codes are stored once per LAYER, not per slot: a scalar-prefetched table
+maps each m-slot to its layer (and that layer's zero points, read from
+SMEM), and the codes BlockSpec follows it. The activation-row axis is the
+outermost grid dimension, tiled like the per-leaf kernels (`row_block`).
 
 `LAUNCHES` counts `pallas_call` constructions at trace time — the parity
 test asserts the whole decode block costs ONE launch on this path.
@@ -44,9 +49,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.quant import QuantSpec, quantize_activations
-from ..compat import CompilerParams
 from . import ops as bp_ops
-from .kernel import _unpack_words
+from .kernel import (_pad_axis, _unpack_words, activation_bits, code_dot,
+                     row_block)
 
 #: pallas_call constructions on the fused program path (trace-time; jit
 #: caching means one launch per distinct block shape, asserted in tests).
@@ -179,35 +184,34 @@ def pack_weights(plan: ProgramKernelPlan, leaves: Sequence):
     return jnp.stack(p_rows), jnp.stack(s_rows)
 
 
-def pack_codes(plan: ProgramKernelPlan, codes: Sequence[jax.Array]):
-    """codes[l]: (B, n_l) uint8 → (S, NT, B, BN), padded with each layer's
-    z_a inside its live tiles and with 0 on fully-padded grid steps."""
-    b = codes[0].shape[0]
+def pack_codes(plan: ProgramKernelPlan, codes: Sequence[jax.Array],
+               br: int):
+    """codes[l]: (B, n_l) uint8 → (L, NT, R, BN) with R = B padded to a
+    multiple of the row block `br`; padded with each layer's z_a inside its
+    live tiles and with 0 on fully-padded grid steps."""
     per_layer = []
     for L, c in zip(plan.layers, codes):
-        c = bp_ops._pad_axis(c, L.bn, 1, value=L.z_a)
+        c = _pad_axis(_pad_axis(c, L.bn, 1, value=L.z_a), br, 0)
         tiles = [
             jnp.pad(c[:, nt * L.bn:(nt + 1) * L.bn],
                     ((0, 0), (0, plan.bn_max - L.bn)),
                     constant_values=L.z_a)
             if nt < L.n_tiles else
-            jnp.zeros((b, plan.bn_max), jnp.uint8)
+            jnp.zeros((c.shape[0], plan.bn_max), jnp.uint8)
             for nt in range(plan.nt_max)]
-        per_layer.append(jnp.stack(tiles))       # (NT, B, BN)
-    return jnp.stack([per_layer[l] for l in plan.slot_layer])
+        per_layer.append(jnp.stack(tiles))       # (NT, R, BN)
+    return jnp.stack(per_layer)
 
 
 @functools.lru_cache(maxsize=512)
 def pack_params(plan: ProgramKernelPlan) -> np.ndarray:
-    """(S, NT, 4) int32 [z_a, z_w, valid, layer] — static numpy, zeros on
-    fully-padded steps so their epilogue terms vanish exactly."""
-    out = np.zeros((plan.slots, plan.nt_max, 4), np.int32)
-    for s, (l, _r) in enumerate(zip(plan.slot_layer, plan.slot_mtile)):
+    """(3·S,) int32 [layer, z_a, z_w] per m-slot — the static scalar-
+    prefetch table (SMEM) the codes BlockSpec and the epilogue read."""
+    out = np.zeros((plan.slots, 3), np.int32)
+    for s, l in enumerate(plan.slot_layer):
         L = plan.layers[l]
-        for nt in range(L.n_tiles):
-            out[s, nt] = (L.z_a, L.z_w, 1, l)
-        out[s, L.n_tiles:, 3] = l
-    return out
+        out[s] = (l, L.z_a, L.z_w)
+    return out.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -216,15 +220,16 @@ def pack_params(plan: ProgramKernelPlan) -> np.ndarray:
 
 def _program_kernel(params_ref, codes_ref, planes_ref, scale_ref, out_ref,
                     *, q_max: int, p_max: int, bn: int, fidelity: str):
-    nt = pl.program_id(1)
+    slot = pl.program_id(1)
+    nt = pl.program_id(2)
 
     @pl.when(nt == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    z_a = params_ref[0, 0, 0]
-    z_w = params_ref[0, 0, 1]
-    a_codes = codes_ref[0, 0]                         # (B, BN) uint8
+    z_a = params_ref[3 * slot + 1]
+    z_w = params_ref[3 * slot + 2]
+    a_codes = codes_ref[0, 0]                         # (br, BN) uint8
     b = a_codes.shape[0]
     bm = out_ref.shape[-1]
     # every plane of the envelope unpacked exactly once per cell (planes of
@@ -236,13 +241,11 @@ def _program_kernel(params_ref, codes_ref, planes_ref, scale_ref, out_ref,
                                       keepdims=True)
     acc = jnp.zeros((b, bm), jnp.int32)
     if fidelity == "code":
-        a_int = a_codes.astype(jnp.int32)
         for i in range(q_max):
-            acc += (1 << i) * jax.lax.dot(
-                a_int, planes[i].astype(jnp.int32),
-                preferred_element_type=jnp.int32)
+            acc += (1 << i) * code_dot(a_codes,
+                                       planes[i].astype(jnp.bfloat16))
     else:  # "bitserial" — codes < 2^p have zero high bits: exact zeros
-        a_bits = [((a_codes >> k) & 1).astype(jnp.int8) for k in range(p_max)]
+        a_bits = activation_bits(a_codes, p_max)
         for i in range(q_max):
             for k in range(p_max):
                 acc += (1 << (i + k)) * jax.lax.dot(
@@ -257,46 +260,55 @@ def _program_kernel(params_ref, codes_ref, planes_ref, scale_ref, out_ref,
 def program_gemv(plan: ProgramKernelPlan, codes_t, planes_t, scale_t,
                  params_t, *, fidelity: str = "code",
                  interpret: bool = False) -> jax.Array:
-    """ONE pallas_call for the whole decode block → (S, B, BM) f32
-    un-activation-scaled outputs, gathered per layer by `gather_outputs`."""
+    """ONE pallas_call for the whole decode block → (S, R, BM) f32
+    un-activation-scaled outputs, gathered per layer by `gather_outputs`.
+    codes_t is (L, NT, R, BN) from `pack_codes`; params_t the `pack_params`
+    table, scalar-prefetched into SMEM."""
     global LAUNCHES
     if fidelity not in ("code", "bitserial"):
         raise ValueError(
             f"fidelity must be 'code' or 'bitserial', got {fidelity!r}")
     LAUNCHES += 1
-    s, nt_max, b, bn = codes_t.shape
+    _l, nt_max, rows, bn = codes_t.shape
+    br = row_block(rows)   # rows is already a multiple of it (pack_codes)
+    s = plan.slots
     wb = plan.bn_max // 32
     bm = plan.bm_max
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(rows // br, s, nt_max),
+        in_specs=[
+            pl.BlockSpec((1, 1, br, bn),
+                         lambda ri, si, ni, tbl: (tbl[3 * si], ni, ri, 0)),
+            pl.BlockSpec((1, 1, plan.q_max, wb, bm),
+                         lambda ri, si, ni, tbl: (si, ni, 0, 0, 0)),
+            pl.BlockSpec((1, 1, 1, bm),
+                         lambda ri, si, ni, tbl: (si, ni, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, br, bm),
+                               lambda ri, si, ni, tbl: (si, ri, 0)),
+    )
     return pl.pallas_call(
         functools.partial(_program_kernel, q_max=plan.q_max,
                           p_max=plan.p_max, bn=plan.bn_max,
                           fidelity=fidelity),
-        grid=(s, nt_max),
-        in_specs=[
-            pl.BlockSpec((1, 1, 4), lambda si, ni: (si, ni, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, b, bn), lambda si, ni: (si, ni, 0, 0)),
-            pl.BlockSpec((1, 1, plan.q_max, wb, bm),
-                         lambda si, ni: (si, ni, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 1, bm), lambda si, ni: (si, ni, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, b, bm), lambda si, ni: (si, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, b, bm), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, rows, bm), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(params_t, codes_t, planes_t, scale_t)
 
 
-def gather_outputs(plan: ProgramKernelPlan, out: jax.Array) -> list:
-    """(S, B, BM) slot outputs → per-layer (B, m_l), un-activation-scaled.
+def gather_outputs(plan: ProgramKernelPlan, out: jax.Array, b: int) -> list:
+    """(S, R, BM) slot outputs → per-layer (b, m_l), un-activation-scaled.
     Slot n-tiles were visited in ascending order per slot, so each layer's
     accumulation order matches the per-leaf kernel's — f32 sums included."""
     slot_of = {(l, r): s for s, (l, r)
                in enumerate(zip(plan.slot_layer, plan.slot_mtile))}
     outs = []
     for l, L in enumerate(plan.layers):
-        parts = [out[slot_of[(l, r)], :, :L.bm] for r in range(L.m_tiles)]
+        parts = [out[slot_of[(l, r)], :b, :L.bm] for r in range(L.m_tiles)]
         outs.append(jnp.concatenate(parts, axis=-1)[:, :L.m])
     return outs
 
@@ -318,11 +330,12 @@ def _run_codes(plan: ProgramKernelPlan, planes_t, scale_t, stacked_codes,
     NOT move inside the trace is the absmax/divide chain that *produces*
     the scale."""
     codes = tuple(stacked_codes[bi][s:s + b] for bi, s, b in layout)
-    codes_t = pack_codes(plan, codes)
+    b = codes[0].shape[0]
+    codes_t = pack_codes(plan, codes, row_block(b))
     params_t = jnp.asarray(pack_params(plan))
     out = program_gemv(plan, codes_t, planes_t, scale_t, params_t,
                        fidelity=fidelity, interpret=interpret)
-    outs = gather_outputs(plan, out)
+    outs = gather_outputs(plan, out, b)
     return tuple(o * stacked_scales[bi][s:s + b]
                  for o, (bi, s, b) in zip(outs, layout))
 

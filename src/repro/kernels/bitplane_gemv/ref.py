@@ -41,6 +41,11 @@ def gemv_bs_ref(a_codes, planes, scale_tiles, *, q: int, p: int,
                          (1 << jnp.arange(q)).astype(jnp.int32))
     sum_a = jnp.sum(a_t, axis=-1)                            # (B, t)
     corr = (acc - z_a * col_sum[None] - z_w * sum_a[..., None]
-            + bn * z_a * z_w)
-    return jnp.einsum("btm,tm->bm", corr.astype(jnp.float32),
-                      scale_tiles.astype(jnp.float32))
+            + bn * z_a * z_w).astype(jnp.float32)
+    scale = scale_tiles.astype(jnp.float32)
+    # the kernel's epilogue order, tile by tile: the integer parts are exact,
+    # so the same f32 sequence makes the two results bitwise comparable
+    out = jnp.zeros((b, m), jnp.float32)
+    for i in range(t):
+        out = out + corr[:, i] * scale[i]
+    return out

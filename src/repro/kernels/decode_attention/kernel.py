@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
-
 NEG_INF = -2.3819763e38
 
 
@@ -107,7 +105,7 @@ def decode_attention_pallas(pos, q, k, v, kv_positions, k_scale, v_scale, *,
             pltpu.VMEM((h, 1), jnp.float32),       # running denom
             pltpu.VMEM((h, d), jnp.float32),       # accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos, q, k, v, kv_positions, k_scale, v_scale)

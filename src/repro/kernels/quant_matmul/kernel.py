@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _unpack_codes(words: jax.Array, q: int, bn: int) -> jax.Array:
@@ -59,7 +58,7 @@ def quant_matmul_pallas(a, codes, scale_tiles, *, q: int, zero: int,
         ],
         out_specs=pl.BlockSpec((b, bm), lambda mi, ni: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, codes, scale_tiles)

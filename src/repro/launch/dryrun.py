@@ -4,8 +4,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production meshes and extract the roofline terms.
 
-MUST be run as its own process (`python -m repro.launch.dryrun --arch …`) —
-the first two lines above force 512 host devices BEFORE jax initializes;
+MUST be run as its own process (`python -m repro.launch.dryrun --arch …`),
+on a CPU host and never on a chip — the first two lines above force 512
+host devices BEFORE jax initializes;
 nothing else in the repo sets this flag (smoke tests and benchmarks see the
 real single device).
 

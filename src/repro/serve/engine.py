@@ -212,12 +212,15 @@ class ServeEngine:
             # the model genuinely does not fit the pool: roll the partial
             # residency back (silent LRU churn would evict the layers we
             # just placed and make compile fail anyway) and serve through
-            # the jit path without a resident decode program
+            # the jit path without a resident decode program. The handles
+            # go too: program-less serving never reads them, and each one
+            # holds its matrix's unpacked codes
             import warnings
             self.placement_fallback = True
             for name in names:
                 if self.mvdram.pool.is_resident(name):
                     self.mvdram.evict(name)
+                self.mvdram.handles.pop(name, None)
             warnings.warn(
                 f"model does not fit the DramPool even after compaction "
                 f"({len(names)}/{len(leaves)} linears placed before "
@@ -415,8 +418,10 @@ class ServeEngine:
         return jnp.where(temperature > 0.0, hot, greedy)
 
     def throughput_tokens_per_s(self, b: int = 1, n: int = 16) -> float:
-        """Measured decode tokens/s on the current backend (CPU here —
-        meaningful for RELATIVE comparisons, e.g. quantized vs dense).
+        """Measured decode tokens/s on the current device (a CPU figure is
+        meaningful only for RELATIVE comparisons, e.g. quantized vs dense).
+        The timed call blocks on its result, so this is device time plus
+        dispatch, not the enqueue.
 
         The masked decode scans to the power-of-two bucket of `n`, so the
         wall-clock includes any frozen tail past `n` — the honest cost of
@@ -424,8 +429,9 @@ class ServeEngine:
         the numerator."""
         import time
         prompts = jnp.zeros((b, 8), jnp.int32)
-        _ = self.generate(prompts, max_new=n)   # warm the bucket executable
+        # warm the bucket executable
+        jax.block_until_ready(self.generate(prompts, max_new=n))
         t0 = time.perf_counter()
-        _ = self.generate(prompts, max_new=n)
+        jax.block_until_ready(self.generate(prompts, max_new=n))
         dt = time.perf_counter() - t0
         return b * n / dt

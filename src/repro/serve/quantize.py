@@ -13,14 +13,12 @@ paper's low-bit path.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from ..core.bitplane import BitplaneWeights, make_bitplane_weights
 from ..core.quant import QuantSpec
-from ..models.params import ParamDef
+from ..models.params import ParamDef, init_leaf
 
 # weight-leaf basenames served by the bit-plane engine
 # w_uk/w_uv stay fp: the MLA absorbed-decode path contracts them per-head
@@ -48,7 +46,9 @@ def _quantize_leaf(w: jax.Array, bits: int) -> BitplaneWeights:
         return make_bitplane_weights(w, spec)
     lead = w.shape[:-2]
     flat = w.reshape((-1,) + w.shape[-2:])
-    parts = [make_bitplane_weights(flat[i], spec)
+    # block on each layer: eager dispatch would otherwise run ahead of the
+    # device and hold many layers' full-size quantize temporaries at once
+    parts = [jax.block_until_ready(make_bitplane_weights(flat[i], spec))
              for i in range(flat.shape[0])]
     stack = lambda xs: jnp.stack(xs).reshape(lead + xs[0].shape)
     return BitplaneWeights(
@@ -59,13 +59,41 @@ def _quantize_leaf(w: jax.Array, bits: int) -> BitplaneWeights:
         n=w.shape[-2], spec=spec)
 
 
+def _servable(path, leaf) -> bool:
+    return bool(path) and path[-1] in QUANT_LEAF_NAMES and leaf.ndim >= 2
+
+
 def quantize_params(params, bits: int):
-    """Concrete params → serving params (bit-plane leaves swapped in)."""
+    """Concrete params → serving params (bit-plane leaves swapped in).
+    Leaves that are already `BitplaneWeights` pass through unchanged."""
     def fn(path, leaf):
-        if path and path[-1] in QUANT_LEAF_NAMES and leaf.ndim >= 2:
+        if not isinstance(leaf, BitplaneWeights) and _servable(path, leaf):
             return _quantize_leaf(leaf, bits)
         return leaf
     return _walk(params, fn)
+
+
+_init_leaf_jit = jax.jit(init_leaf, static_argnums=0)
+
+
+def init_quantized_params(defs, key: jax.Array, bits: int):
+    """`quantize_params(init_params(defs, key), bits)` one leaf at a time:
+    each leaf is initialized (same per-leaf keys as `init_params`),
+    quantized, and its float copy dropped before the next leaf, so peak
+    memory is one float leaf plus the packed tree — a full-width model
+    never exists in float32. The init is jitted so XLA fuses the random
+    draw instead of materializing its intermediates at full leaf size."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        defs, is_leaf=lambda x: isinstance(x, ParamDef))
+    keys = jax.random.split(key, len(leaves))
+    vals = []
+    for (path, d), k in zip(leaves, keys):
+        leaf = _init_leaf_jit(d, k)
+        names = tuple(getattr(p, "key", p) for p in path)
+        if _servable(names, leaf):
+            leaf = _quantize_leaf(leaf, bits)
+        vals.append(leaf)
+    return jax.tree_util.tree_unflatten(treedef, vals)
 
 
 def quantize_defs(defs, bits: int):

@@ -7,13 +7,14 @@ registry — every call site resolves through `core.backends`.
 """
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import backends
-from repro.core.backends import (JNP, PALLAS, SIM, Backend, get_backend,
-                                 register_backend, resolve_impl)
+from repro.core.backends import (JNP, PALLAS, PALLAS_INTERPRET, SIM, Backend,
+                                 get_backend, register_backend, resolve_impl)
 from repro.core.bitplane import make_bitplane_weights
 from repro.core.engine import EngineLinear, MVDRAMEngine
 from repro.core.pud.gemv import PudGeometry
@@ -54,7 +55,11 @@ def test_registry_rejects_unknown():
 
 def test_kernel_impl_strings_live_in_backends():
     assert JNP.kernel_impl == "jnp"
-    assert PALLAS.kernel_impl in ("pallas", "pallas_interpret")
+    if jax.default_backend() == "tpu":
+        assert PALLAS.kernel_impl == "pallas"
+    else:   # never a quiet switch to interpret mode off-TPU
+        with pytest.raises(RuntimeError, match="PALLAS_INTERPRET"):
+            PALLAS.kernel_impl
     assert SIM.kernel_impl is None
     # the pre-registry impl string still resolves (forced interpret mode)
     assert get_backend("pallas_interpret").kernel_impl == "pallas_interpret"
@@ -88,7 +93,7 @@ def test_sim_oracle_paths_do_not_stage_resident_rows(rng):
 
 def test_resolve_impl():
     assert resolve_impl(None) == backends.DEFAULT.kernel_impl
-    assert resolve_impl(PALLAS) == PALLAS.kernel_impl
+    assert resolve_impl(PALLAS_INTERPRET) == "pallas_interpret"
     assert resolve_impl("pallas_interpret") == "pallas_interpret"
     fn = lambda x, w, ab: x                     # noqa: E731
     assert resolve_impl(fn) is fn

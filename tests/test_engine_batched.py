@@ -28,7 +28,7 @@ def test_gemv_batched_all_modes_agree(rng):
     eng, h = _engine_with_matrix(rng)
     A = jnp.asarray(rng.normal(size=(3, 48)), jnp.float32)
     out_j = eng.gemv(h, A, mode="jnp")
-    out_p = eng.gemv(h, A, mode="pallas")
+    out_p = eng.gemv(h, A, mode="pallas_interpret")
     out_s, rep = eng.gemv(h, A, mode="sim")
     assert out_j.shape == out_p.shape == out_s.shape == (3, 12)
     assert isinstance(rep, BatchReport) and rep.batch == 3

@@ -169,7 +169,7 @@ def test_bankarray_majx_uses_per_tile_fault_keys():
 # Satellite (c): faults-off bit-identity, property-tested
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)   # the first example compiles
 @given(layers=st.integers(min_value=1, max_value=4),
        b=st.sampled_from([1, 2, 6]),
        seed=st.integers(min_value=0, max_value=50))

@@ -118,8 +118,46 @@ def test_kernels_agree_with_engine_modes(rng):
     h = eng.register("m", w, QuantSpec(bits=3), a_spec=QuantSpec(bits=4))
     o_sim, _ = eng.gemv(h, a, mode="sim")
     o_jnp = eng.gemv(h, a, mode="jnp")
-    o_pl = eng.gemv(h, a[None], mode="pallas")[0]
+    o_pl = eng.gemv(h, a[None], mode="pallas_interpret")[0]
     np.testing.assert_allclose(np.asarray(o_jnp), np.asarray(o_sim),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(o_jnp), np.asarray(o_pl),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("fidelity", ["code", "bitserial"])
+def test_row_tiled_kernels_match_single_block(rng, fidelity):
+    """Past ROW_BLOCK rows (a prefill chunk) the activation rows tile over
+    a grid axis; every row still matches its own single-block launch —
+    bitwise on the integer paths (code kernel, fused group), to f32
+    rounding on the float kernel."""
+    from repro.kernels.bitplane_gemv import program as bp_prog
+    from repro.kernels.bitplane_gemv.kernel import ROW_BLOCK
+    n, m, rows = 300, 130, ROW_BLOCK + 44
+    spec = QuantSpec(bits=4)
+    ws = [make_bitplane_weights(
+        jnp.asarray(rng.normal(size=(n, m)), jnp.float32), QuantSpec(bits=q))
+        for q in (2, 3)]
+    a = jnp.asarray(rng.normal(size=(rows, n)), jnp.float32)
+    aq = quantize_activations(a, spec)
+    tiled = bp.bitplane_gemv_codes(aq.values, ws[0], 4, aq.zero,
+                                   impl="pallas_interpret", fidelity=fidelity)
+    blocks = [bp.bitplane_gemv_codes(aq.values[s:s + 4], ws[0], 4, aq.zero,
+                                     impl="pallas_interpret",
+                                     fidelity=fidelity)
+              for s in (0, ROW_BLOCK, rows - 4)]
+    np.testing.assert_array_equal(
+        np.asarray(tiled)[[0, 1, 2, 3, ROW_BLOCK, ROW_BLOCK + 1,
+                           ROW_BLOCK + 2, ROW_BLOCK + 3, -4, -3, -2, -1]],
+        np.concatenate([np.asarray(b) for b in blocks]))
+    # the float kernel's f32 dot may sum in a shape-dependent order
+    f_tiled = bp.bitplane_gemv(a, ws[1], impl="pallas_interpret")
+    f_last = bp.bitplane_gemv(a[-4:], ws[1], impl="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(f_tiled)[-4:], np.asarray(f_last),
+                               rtol=1e-5, atol=1e-5)
+    fused = bp_prog.fused_group_linears(a, ws, 4, fidelity=fidelity,
+                                        interpret=True)
+    for w, out in zip(ws, fused):
+        ref = bp.bitplane_gemv_bitserial(a, w, spec, impl="pallas_interpret",
+                                         fidelity=fidelity)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

@@ -45,7 +45,8 @@ step = make_train_step(model, AdamWConfig(warmup_steps=1, total_steps=10),
 # single device
 p1, _, m1 = jax.jit(step)(params, opt, batch)
 # 2x4 mesh
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model=4)
 with axis_rules(mesh, None):
     sh = defs_to_shardings(defs)
     params_s = jax.device_put(params, sh)
@@ -102,12 +103,13 @@ from repro.train.loop import Trainer, TrainerConfig
 
 d = {str(tmp_path)!r}
 cfg = tiny_config("llama2-7b")
-mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh1 = make_host_mesh(model=4)
 tr1 = Trainer(cfg, AdamWConfig(warmup_steps=2, total_steps=50),
               TrainerConfig(ckpt_dir=d, ckpt_every=10, ckpt_async=False),
               mesh=mesh1, global_batch=4, seq_len=16)
 tr1.run(10)
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = make_host_mesh(model=2)
 tr2 = Trainer(cfg, AdamWConfig(warmup_steps=2, total_steps=50),
               TrainerConfig(ckpt_dir=d, ckpt_every=10, ckpt_async=False),
               mesh=mesh2, global_batch=4, seq_len=16)

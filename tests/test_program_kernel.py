@@ -239,3 +239,14 @@ def test_run_kernel_input_validation(rng):
     for o, r in zip(one, ref):
         assert o.ndim == 1
         assert np.array_equal(np.asarray(o), np.asarray(r)[0])
+
+
+def test_run_kernel_needs_a_tpu_or_interpret(rng):
+    """The compiled kernel never quietly becomes its interpreter: off-TPU,
+    `run_kernel` without `interpret=True` raises."""
+    import jax
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled kernel runs here")
+    _eng, _hs, prog, X = _build(BLOCKS[2], 2, rng)
+    with pytest.raises(RuntimeError, match="PALLAS_INTERPRET"):
+        prog.run_kernel(X)

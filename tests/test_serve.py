@@ -12,8 +12,9 @@ from repro.core.bitplane import BitplaneWeights
 from repro.models.model import Model, param_defs
 from repro.models.params import init_params
 from repro.serve.engine import ServeEngine
-from repro.serve.quantize import (QUANT_LEAF_NAMES, quantize_defs,
-                                  quantize_params, serving_bytes)
+from repro.serve.quantize import (QUANT_LEAF_NAMES, init_quantized_params,
+                                  quantize_defs, quantize_params,
+                                  serving_bytes)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -32,6 +33,30 @@ def test_quantize_params_swaps_expected_leaves():
     # stacked leaves keep the stack dim on the packed planes
     assert stage["attn"]["wq"].planes.shape[0] == params["stages"]["0"][
         "attn"]["wq"].shape[0]
+
+
+def test_init_quantized_params_matches_quantize_after_init():
+    """Leaf-at-a-time init+quantize builds the same packed tree as
+    quantizing a whole float model, and a ServeEngine serves that tree
+    as it is (its leaves are already BitplaneWeights)."""
+    cfg = dataclasses.replace(tiny_config("llama2-7b"), dtype="float32",
+                              weight_bits=4)
+    defs = param_defs(cfg)
+    params = init_params(defs, KEY)
+    packed = init_quantized_params(defs, KEY, bits=4)
+    two_step = quantize_params(params, bits=4)
+    assert (jax.tree_util.tree_structure(packed)
+            == jax.tree_util.tree_structure(two_step))
+    for a, b in zip(jax.tree_util.tree_leaves(packed),
+                    jax.tree_util.tree_leaves(two_step)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    prompts = jax.random.randint(KEY, (2, 6), 0, cfg.vocab_size,
+                                 dtype=jnp.int32)
+    out = ServeEngine(cfg, packed, max_seq=16, quantized=True).generate(
+        prompts, max_new=4)
+    ref = ServeEngine(cfg, params, max_seq=16, quantized=True).generate(
+        prompts, max_new=4)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 def test_quantize_defs_matches_quantize_params_structure():

@@ -1,48 +1,22 @@
-"""Kernel micro-benchmarks (CPU wall-clock — RELATIVE numbers only; the
-TPU path is priced by the dry-run roofline) + a large-shape correctness
-check of the interpret-mode kernel against the oracle."""
+"""Large-shape correctness check of the interpret-mode bit-plane kernel
+against the jnp oracle (CPU; no timing: kernel speed is measured on the
+chip by `bench/run.py`)."""
 from __future__ import annotations
 
-import time
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bitplane import make_bitplane_weights
-from repro.core.quant import QuantSpec, quantize_weights
+from repro.core.quant import QuantSpec
 from repro.kernels.bitplane_gemv import ops as bp
-from repro.kernels.quant_matmul import ops as qm
 
 
-def _time(fn, *args, n=5):
-    fn(*args).block_until_ready()
-    t0 = time.perf_counter()
-    for _ in range(n):
-        out = fn(*args)
-    out.block_until_ready()
-    return (time.perf_counter() - t0) / n * 1e6   # µs
-
-
-def kernel_microbench(emit):
+def kernel_interpret_check(emit):
     rng = np.random.default_rng(0)
-    n, m, b = 4096, 4096, 4
-    w = jnp.asarray(rng.normal(size=(n, m)), jnp.float32)
+    n, b = 4096, 4
+    w = jnp.asarray(rng.normal(size=(n, 512)), jnp.float32)
     a = jnp.asarray(rng.normal(size=(b, n)), jnp.float32)
-    dense_w = w.astype(jnp.bfloat16)
-    dense = jax.jit(lambda x: (x.astype(jnp.bfloat16) @ dense_w
-                               ).astype(jnp.float32))
-    emit("kernel.dense_bf16_us", _time(dense, a))
-    for q in (2, 4):
-        bw = make_bitplane_weights(w, QuantSpec(bits=q))
-        f = jax.jit(lambda x, bw=bw: bp.bitplane_gemv(x, bw, impl="jnp"))
-        emit(f"kernel.bitplane_q{q}_jnp_us", _time(f, a),
-             f"packed bytes={int(bw.planes.size * 4)}")
-        wq = quantize_weights(w, QuantSpec(bits=q))
-        g = jax.jit(lambda x, wq=wq: qm.quant_matmul(x, wq, impl="jnp"))
-        emit(f"kernel.quant_matmul_q{q}_jnp_us", _time(g, a))
-    # interpret-mode kernel correctness at a production-ish shape
-    bw = make_bitplane_weights(w[:, :512], QuantSpec(bits=4))
+    bw = make_bitplane_weights(w, QuantSpec(bits=4))
     ref = bp.bitplane_gemv(a, bw, impl="jnp")
     got = bp.bitplane_gemv(a, bw, impl="pallas_interpret")
     err = float(jnp.abs(ref - got).max() / (jnp.abs(ref).max() + 1e-9))
@@ -50,20 +24,4 @@ def kernel_microbench(emit):
     assert err < 1e-4
 
 
-def serve_relative_bench(emit):
-    """Measured decode throughput, dense bf16 vs bit-plane-served weights
-    (tiny model, CPU): demonstrates the end-to-end serving path."""
-    import dataclasses
-    from repro.configs import tiny_config
-    from repro.models.model import param_defs
-    from repro.models.params import init_params
-    from repro.serve.engine import ServeEngine
-    cfg = dataclasses.replace(tiny_config("llama2-7b"), weight_bits=2)
-    params = init_params(param_defs(cfg), jax.random.PRNGKey(0))
-    for tag, quantized in (("dense", False), ("bitplane_q2", True)):
-        eng = ServeEngine(cfg, params, max_seq=64, quantized=quantized)
-        emit(f"serve.{tag}.tok_s",
-             eng.throughput_tokens_per_s(b=2, n=16))
-
-
-ALL = [kernel_microbench, serve_relative_bench]
+ALL = [kernel_interpret_check]

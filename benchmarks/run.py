@@ -3,8 +3,9 @@
     PYTHONPATH=src python -m benchmarks.run [--only substring]
 
 Prints ``name,value,derived`` CSV. PUD-side numbers come from the calibrated
-DDR4-2400 command model (this container has no FPGA testbed); kernel/serve
-numbers are measured CPU wall-clock (relative); roofline rows aggregate the
+DDR4-2400 command model (this container has no FPGA testbed); the kernel
+row is an interpret-mode correctness check, not a timing (speed is
+measured on the chip by `bench/run.py`); roofline rows aggregate the
 multi-pod dry-run artifacts if present.
 """
 from __future__ import annotations

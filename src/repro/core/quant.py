@@ -139,6 +139,7 @@ def slice_quantized_cols(wq: QuantizedTensor, lo: int, hi: int
         col_sum=None if wq.col_sum is None else wq.col_sum[lo:hi])
 
 
+@jax.named_scope("act_quant")
 def quantize_activations(a: jax.Array, spec: QuantSpec) -> QuantizedTensor:
     """Quantize activations (..., N) per-row (per-token) to unsigned codes."""
     af = a.astype(jnp.float32)

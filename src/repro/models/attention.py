@@ -157,6 +157,7 @@ def _attend(q, k, v, window, cap, scale):
 # GQA
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def gqa_forward(x, p, acfg: AttnConfig, window: Optional[int],
                 positions: jax.Array, act_bits=None, impl=None,
                 return_kv: bool = False):
@@ -193,6 +194,7 @@ def _kv_dequant(q, scale):
     return q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)
 
 
+@jax.named_scope("attention")
 def gqa_decode(x, p, acfg: AttnConfig, window: Optional[int], cache: dict,
                pos: jax.Array, act_bits=None, impl=None,
                attn_impl: str = "sdpa"):
@@ -219,21 +221,24 @@ def gqa_decode(x, p, acfg: AttnConfig, window: Optional[int], cache: dict,
     slot = pos if window is None else pos % jnp.asarray(sc)       # (B,)
     lane = jnp.arange(b)
     new_cache = {}
+    with jax.named_scope("kv_write"):
+        if int8_kv:
+            kq, ks = _kv_quant(k)
+            vq, vs = _kv_quant(v)
+            k_all = cache["k"].at[lane, slot].set(kq[:, 0])
+            v_all = cache["v"].at[lane, slot].set(vq[:, 0])
+            ks_all = cache["k_scale"].at[lane, slot].set(ks[:, 0])
+            vs_all = cache["v_scale"].at[lane, slot].set(vs[:, 0])
+            new_cache.update(k_scale=ks_all, v_scale=vs_all)
+        else:
+            k_all = cache["k"].at[lane, slot].set(k[:, 0])
+            v_all = cache["v"].at[lane, slot].set(v[:, 0])
+        pos_all = cache["positions"].at[lane, slot].set(pos)      # (B, Sc)
     if int8_kv:
-        kq, ks = _kv_quant(k)
-        vq, vs = _kv_quant(v)
-        k_all = cache["k"].at[lane, slot].set(kq[:, 0])
-        v_all = cache["v"].at[lane, slot].set(vq[:, 0])
-        ks_all = cache["k_scale"].at[lane, slot].set(ks[:, 0])
-        vs_all = cache["v_scale"].at[lane, slot].set(vs[:, 0])
-        new_cache.update(k_scale=ks_all, v_scale=vs_all)
         k_use = _kv_dequant(k_all, ks_all).astype(x.dtype)
         v_use = _kv_dequant(v_all, vs_all).astype(x.dtype)
     else:
-        k_all = cache["k"].at[lane, slot].set(k[:, 0])
-        v_all = cache["v"].at[lane, slot].set(v[:, 0])
         k_use, v_use = k_all, v_all
-    pos_all = cache["positions"].at[lane, slot].set(pos)          # (B, Sc)
     if attn_impl != "sdpa" and acfg.softcap is None:
         # fused flash-decode kernel: reads the RAW (possibly int8) cache —
         # no dequant/convert materialization in HBM
@@ -280,6 +285,7 @@ def gqa_cache_init(cfg_batch: int, slots: int, acfg: AttnConfig, dtype,
 # MLA (deepseek-v2-lite flavour)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def mla_forward(x, p, acfg: AttnConfig, mla: MLAConfig, positions,
                 act_bits=None, impl=None, return_kv: bool = False):
     """Full-sequence MLA. Params: wq (E, H·(dn+dr)), w_dkv (E, L+dr),
@@ -310,6 +316,7 @@ def mla_forward(x, p, acfg: AttnConfig, mla: MLAConfig, positions,
     return (out, (c_kv, k_rope[:, :, 0])) if return_kv else out
 
 
+@jax.named_scope("attention")
 def mla_decode(x, p, acfg: AttnConfig, mla: MLAConfig, cache: dict, pos,
                act_bits=None, impl=None):
     """Absorbed one-token MLA: cache holds only (c_kv, k_rope)."""
@@ -327,9 +334,10 @@ def mla_decode(x, p, acfg: AttnConfig, mla: MLAConfig, cache: dict, pos,
     cos, sin = rope_frequencies(dr, acfg.rope_base, pos[:, None])
     q_rope = apply_rope(q[..., dn:], cos, sin)
     k_rope = apply_rope(k_rope, cos, sin)
-    ckv_all = cache["c_kv"].at[lane, pos].set(c_kv[:, 0])
-    kr_all = cache["k_rope"].at[lane, pos].set(k_rope[:, 0, 0])
-    pos_all = cache["positions"].at[lane, pos].set(pos)          # (B, S)
+    with jax.named_scope("kv_write"):
+        ckv_all = cache["c_kv"].at[lane, pos].set(c_kv[:, 0])
+        kr_all = cache["k_rope"].at[lane, pos].set(k_rope[:, 0, 0])
+        pos_all = cache["positions"].at[lane, pos].set(pos)      # (B, S)
     ckv_all = constrain(ckv_all, "batch", "kv_seq", None)
     # absorb q_nope through W_uk: (B,1,H,dn)·(L,H,dn) → (B,1,H,L)
     q_abs = jnp.einsum("bshd,lhd->bshl", q[..., :dn].astype(jnp.float32),

@@ -92,6 +92,7 @@ def layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array,
     return (y * scale + bias).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def norm(x, p, norm_type: str):
     if norm_type == "layernorm":
         return layernorm(x, p["scale"], p["bias"])
@@ -126,6 +127,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
 
 # -- FFN ---------------------------------------------------------------------
 
+@jax.named_scope("ffn")
 def ffn(x: jax.Array, p, ffn_type: str, act_bits=None, impl=None):
     """GLU (SwiGLU/GeGLU) or classic 2-layer MLP."""
     if ffn_type == "glu":

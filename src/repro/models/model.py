@@ -279,13 +279,15 @@ class Model:
     def _embed_in(self, params, batch):
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        if cfg.input_mode == "embeddings":
-            x = batch["embeddings"].astype(dt)
-        else:
-            x = embed(batch["tokens"], params["embed"].astype(dt),
-                      cfg.embed_scale, cfg.d_model)
+        with jax.named_scope("embed"):
+            if cfg.input_mode == "embeddings":
+                x = batch["embeddings"].astype(dt)
+            else:
+                x = embed(batch["tokens"], params["embed"].astype(dt),
+                          cfg.embed_scale, cfg.d_model)
         return constrain(x, "batch", "seq", "embed")
 
+    @jax.named_scope("lm_head")
     def _logits(self, params, x):
         cfg = self.cfg
         if cfg.tie_embeddings:
@@ -426,11 +428,12 @@ class Model:
         """
         cfg, plan = self.cfg, stack_plan(self.cfg)
         dt = jnp.dtype(cfg.dtype)
-        if cfg.input_mode == "embeddings":
-            x = inp.astype(dt)[:, None]
-        else:
-            x = embed(inp[:, None], params["embed"].astype(dt),
-                      cfg.embed_scale, cfg.d_model)
+        with jax.named_scope("embed"):
+            if cfg.input_mode == "embeddings":
+                x = inp.astype(dt)[:, None]
+            else:
+                x = embed(inp[:, None], params["embed"].astype(dt),
+                          cfg.embed_scale, cfg.d_model)
         x = constrain(x, "batch", None, "embed")
         new_cache: dict = {}
 

@@ -52,6 +52,7 @@ from ..models.config import ModelConfig
 from ..models.model import Model
 from ..parallel.sharding import axis_rules, logical_to_pspec
 from .quantize import quantize_params
+from .spans import phase
 
 # Independent linears of one block — they read the SAME input, so their
 # tiles may share waves in the compiled decode program (q/k/v on the
@@ -121,7 +122,8 @@ class ServeEngine:
         self.placement_fallback = False
         model_impl = impl
         if quantized:
-            params = quantize_params(params, cfg.weight_bits)
+            with phase("quantize"):
+                params = quantize_params(params, cfg.weight_bits)
             # residency session: the whole model co-resides in one pool,
             # and every lane-batched quantized linear routes through the
             # engine against those resident weights. on_full="raise" so a
@@ -141,7 +143,8 @@ class ServeEngine:
                     on_full="spill" if spill_tier else "raise")
             else:
                 self.mvdram = MVDRAMEngine(on_full="raise")
-            self.decode_program = self._place_model(params, act_bits)
+            with phase("place"):
+                self.decode_program = self._place_model(params, act_bits)
             model_impl = EngineLinear(self.mvdram,
                                       backend=backends.get_backend(impl))
         self.params = params

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from ..core.bitplane import BitplaneWeights, make_bitplane_weights
 from ..core.quant import QuantSpec
 from ..models.params import ParamDef, init_leaf
+from .spans import phase
 
 # weight-leaf basenames served by the bit-plane engine
 # w_uk/w_uv stay fp: the MLA absorbed-decode path contracts them per-head
@@ -85,14 +86,16 @@ def init_quantized_params(defs, key: jax.Array, bits: int):
     draw instead of materializing its intermediates at full leaf size."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
         defs, is_leaf=lambda x: isinstance(x, ParamDef))
-    keys = jax.random.split(key, len(leaves))
-    vals = []
-    for (path, d), k in zip(leaves, keys):
-        leaf = _init_leaf_jit(d, k)
-        names = tuple(getattr(p, "key", p) for p in path)
-        if _servable(names, leaf):
-            leaf = _quantize_leaf(leaf, bits)
-        vals.append(leaf)
+    with phase("init"):
+        keys = jax.random.split(key, len(leaves))
+        vals = []
+        for (path, d), k in zip(leaves, keys):
+            leaf = _init_leaf_jit(d, k)
+            names = tuple(getattr(p, "key", p) for p in path)
+            if _servable(names, leaf):
+                leaf = _quantize_leaf(leaf, bits)
+            vals.append(leaf)
+        jax.block_until_ready(vals)
     return jax.tree_util.tree_unflatten(treedef, vals)
 
 

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from ..models.config import ModelConfig
 from .engine import _CACHE_AXES, ServeEngine
+from .spans import span
 
 
 @dataclasses.dataclass
@@ -162,9 +163,13 @@ class ContinuousBatcher:
                     pos = jnp.where(active, pos0 + t, max_seq - 1)
                     logits, new_cache = model.decode_step(params, cache,
                                                           tok, pos)
-                    new_cache = self._freeze_lanes(new_cache, cache, active)
-                    sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    nxt = jnp.where(t == steps - 1, sampled, nxt)
+                    with jax.named_scope("freeze_lanes"):
+                        new_cache = self._freeze_lanes(new_cache, cache,
+                                                       active)
+                    with jax.named_scope("sample"):
+                        sampled = jnp.argmax(logits, axis=-1).astype(
+                            jnp.int32)
+                        nxt = jnp.where(t == steps - 1, sampled, nxt)
                     return (new_cache, nxt), None
 
                 (cache, nxt), _ = jax.lax.scan(
@@ -228,10 +233,12 @@ class ContinuousBatcher:
     def _admit(self):
         for i, lane in enumerate(self.lanes):
             if lane.free and self.queue:
-                req = self.queue.pop(0)
-                lane.req, lane.pos, lane.fed = req, 0, 0
-                lane.last_tok = req.prompt[0]
-                self.cache = self._reset(self.cache, jnp.int32(i))
+                req = self.queue[0]
+                with span("serve.admit", rid=req.rid):
+                    self.queue.pop(0)
+                    lane.req, lane.pos, lane.fed = req, 0, 0
+                    lane.last_tok = req.prompt[0]
+                    self.cache = self._reset(self.cache, jnp.int32(i))
 
     def _plan_steps(self) -> list:
         """Per-lane inner-step budget for this tick: 0 free / 1 decode /
@@ -274,14 +281,42 @@ class ContinuousBatcher:
                 self.sim_time_s += cost
 
     def tick(self):
-        self._admit()
-        steps = self._plan_steps()
-        trip_need = max(steps)
-        if trip_need == 0:
-            return                      # nothing in flight, nothing queued
-        # power-of-two trip bucket: ≤ log2(prefill_chunk)+1 executables
-        trip = min(self.prefill_chunk, 1 << (trip_need - 1).bit_length())
-        self._account_program(steps)
+        """One synchronized step, as host spans: `serve.tick` (args `trip`
+        and active `lanes`) tiled by `serve.admit` (one per admitted
+        request), `serve.plan`, `serve.stage` (host→device copies),
+        `serve.dispatch` (the jitted call), `serve.wait` (the sampled
+        tokens' `device_get`) and `serve.commit`."""
+        with span("serve.tick") as tick_span:
+            self._admit()
+            with span("serve.plan"):
+                steps = self._plan_steps()
+                trip_need = max(steps)
+                if trip_need == 0:
+                    return              # nothing in flight, nothing queued
+                # power-of-two trip bucket: ≤ log2(prefill_chunk)+1 executables
+                trip = min(self.prefill_chunk,
+                           1 << (trip_need - 1).bit_length())
+                tick_span.set_metadata(trip=trip,
+                                       lanes=sum(s > 0 for s in steps))
+                self._account_program(steps)
+                tok_buf, poss = self._feed(steps, trip)
+            with span("serve.stage"):
+                args = (jnp.asarray(tok_buf, jnp.int32),
+                        jnp.asarray(poss, jnp.int32),
+                        jnp.asarray(steps, jnp.int32))
+            with span("serve.dispatch"):
+                self.cache, nxt = self._tick_fn(trip)(self.params,
+                                                      self.cache, *args)
+            with span("serve.wait"):
+                nxt = jax.device_get(nxt)
+            with span("serve.commit"):
+                self._commit(steps, nxt)
+                self.ticks += 1
+
+    def _feed(self, steps: list, trip: int) -> tuple:
+        """Per-lane (trip,) token rows and start positions of this tick: a
+        prompt chunk, the last sampled token, or nothing (a free lane
+        writes to the frozen scratch slot)."""
         tok_buf = []
         poss = []
         for lane, s in zip(self.lanes, steps):
@@ -295,11 +330,11 @@ class ContinuousBatcher:
             else:
                 tok_buf.append([lane.last_tok] + [0] * (trip - 1))
                 poss.append(lane.pos)
-        self.cache, nxt = self._tick_fn(trip)(
-            self.params, self.cache,
-            jnp.asarray(tok_buf, jnp.int32), jnp.asarray(poss, jnp.int32),
-            jnp.asarray(steps, jnp.int32))
-        nxt = jax.device_get(nxt)
+        return tok_buf, poss
+
+    def _commit(self, steps: list, nxt):
+        """Advance every busy lane by its steps; take each lane's sampled
+        token where its prompt is done, and retire finished requests."""
         for i, lane in enumerate(self.lanes):
             if lane.free:
                 continue
@@ -322,4 +357,3 @@ class ContinuousBatcher:
                 lane.req.finish_s = self.sim_time_s
                 self.finished.append(lane.req)
                 lane.req = None
-        self.ticks += 1

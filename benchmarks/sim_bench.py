@@ -585,9 +585,12 @@ def kernel_dots_issued(emit):
     a = jnp.asarray(rng.normal(size=(4, N)), jnp.float32)
     bw = make_bitplane_weights(w, QuantSpec(bits=Q))
     spec = QuantSpec(bits=P)
-    emit("kernel.bitserial_dots_per_tile", dots_per_tile(Q, P, "bitserial"))
-    emit("kernel.code_dots_per_tile", dots_per_tile(Q, P, "code"),
-         "the §V-D linearity collapse: q instead of q·p")
+    bn, _bm = bp._pick_blocks(N, M, None, None)
+    tile = dict(bn=bn, z_a=spec.zero_point)
+    emit("kernel.bitserial_dots_per_tile",
+         dots_per_tile(Q, P, "bitserial", **tile))
+    emit("kernel.code_dots_per_tile", dots_per_tile(Q, P, "code", **tile),
+         "the §V-D linearity collapse on both operands: 1 instead of q·p")
     outs = {}
     for fid in ("bitserial", "code"):
         def f(x, fid=fid):

@@ -13,29 +13,40 @@ TPU adaptation of the paper's §VI horizontal layout:
     epilogue, computed per reduction tile so per-group scales stay local.
 
 Bit-serial fidelity levels (the §V-D linearity collapse): the mathematics
-    Σ_k 2^k · (a^(k) · W^(i))  =  (Σ_k 2^k a^(k)) · W^(i)  =  a_codes · W^(i)
-means the p activation-plane dots per weight plane collapse into ONE integer
-dot against the raw codes — both sides are exact integer arithmetic, so the
-results are identical, not approximations. `fidelity="code"` (default)
-issues q dots per tile; `fidelity="bitserial"` retains the fully decomposed
-q·p-dot schedule — the command-for-command analogue of what the DRAM
-executes — as the tested-equal oracle. `dots_per_tile` exposes the issue
-count the benchmark trajectory records.
+    Σ_k Σ_i 2^(k+i) · (a^(k) · W^(i))  =  (Σ_k 2^k a^(k)) · (Σ_i 2^i W^(i))
+                                       =  a_codes · w_codes
+holds for both operands, so the q·p plane dots of a tile collapse into ONE
+integer dot of the activation codes against the weight codes — exact
+integer arithmetic on both sides, so the results are identical, not
+approximations. `fidelity="code"` (default) folds the q unpacked weight
+planes into one code tile in int32 (`w = Σ_i bit_i << i`) and issues one
+dot per tile; `fidelity="bitserial"` retains the fully decomposed q·p-dot
+schedule — the command-for-command analogue of what the DRAM executes — as
+the tested-equal oracle. `dots_per_tile` gives the issue count.
 
-Shared structure: `_unpack_words` expansion of every weight plane is hoisted
-out of the (i, k) accumulation loops — each plane is unpacked exactly once
-per tile regardless of fidelity. Both kernels accumulate across the
-reduction grid axis into the output block (grid = (row_tiles, m_tiles,
-n_tiles), out indexed by (row, m) — revisited blocks persist in VMEM,
-initialized at n==0). The activation-row axis is tiled (`row_block`) so a
-prefill's B·S rows never have to fit VMEM at once.
+Zero points, centred (code fidelity): with `ac = a_codes − z_a`,
 
-Mosaic constraints the layout follows: the per-tile scales arrive as a
-(T, 1, M) array so their (1, bm) block spans the full second-minor dim, and
-the "code" dot runs bf16×bf16 with f32 accumulation — Mosaic has no int32
-matmul. That dot stays exact: codes ≤ 255 and 0/1 planes are exact in bf16,
-and a tile's partial sum ≤ bn·255 < 2^24 is exact in f32; plane sums then
-accumulate in int32.
+    Σ_j (a_j − z_a)(w_j − z_w)  =  ac · w  −  z_w · Σ_j ac_j
+
+which is the same integer as the bit-serial epilogue's
+`acc − z_a·col_sum − z_w·Σa + bn·z_a·z_w`, without a column-sum reduction
+of the weights. Reduction rows padded with codes at z_a centre to 0 and
+meet zero weight bits, so they add nothing and no `bn·z_a·z_w` term is
+left.
+
+Exactness bound. The dot runs bf16×bf16 with f32 accumulation (Mosaic has
+no int32 matmul). |ac| ≤ 255 and w ≤ 255 are exact in bf16 and every
+product in f32; the sum is exact while every partial sum stays below 2^24,
+i.e. while `bn · max(z_a, 2^p−1−z_a) · (2^q−1) < 2^24` (`one_dot_exact`:
+static in q, p, z_a and bn). Past it the code path keeps one dot per
+weight plane (each partial sum ≤ bn·255) and sums the planes in int32.
+
+Both kernels accumulate across the reduction grid axis into the output
+block (grid = (row_tiles, m_tiles, n_tiles), out indexed by (row, m) —
+revisited blocks persist in VMEM, initialized at n==0). The activation-row
+axis is tiled (`row_block`) so a prefill's B·S rows never have to fit VMEM
+at once. The per-tile scales arrive as a (T, 1, M) array so their (1, bm)
+block spans the full second-minor dim.
 """
 from __future__ import annotations
 
@@ -49,6 +60,8 @@ from jax.experimental.pallas import tpu as pltpu
 #: per-leaf pallas_call constructions (trace-time) — the contrast counter
 #: for the fused program path's one-launch-per-block assertion.
 LAUNCHES = 0
+#: of those, the launches built with the one-dot code body (trace-time)
+ONE_DOT_LAUNCHES = 0
 
 
 #: activation rows per grid step: decode batches fit one block, prefill
@@ -62,6 +75,42 @@ def _unpack_words(words: jax.Array, bn: int) -> jax.Array:
     shifts = jnp.arange(32, dtype=jnp.uint32)[None, :, None]
     bits = (words[:, None, :] >> shifts) & jnp.uint32(1)
     return bits.reshape(w * 32, bm)[:bn].astype(jnp.int8)
+
+
+#: field width f → the (shift, mask) steps that spread the low 32/f bits of
+#: a word to every f-th bit (the classic bit-interleave)
+_SPREAD = {
+    2: ((8, 0x00FF00FF), (4, 0x0F0F0F0F), (2, 0x33333333), (1, 0x55555555)),
+    4: ((12, 0x000F000F), (6, 0x03030303), (3, 0x11111111)),
+    8: ((14, 0x00030003), (7, 0x01010101)),
+}
+
+
+def _unpack_codes(words: list, bn: int) -> jax.Array:
+    """q planes of (W, bm) uint32 → (bn, bm) int32 weight codes
+    Σ_i bit_i << i, in [0, 2^q − 1].
+
+    The planes are combined on whole words first: the 32 rows of a word
+    split into f chunks of 32/f rows (f = 2, 4 or 8, the least ≥ q); each
+    plane's chunk is spread to one f-bit field a row, plane i at bit i, and
+    the planes OR together. Each element then costs one shift and one mask
+    whatever q is; the combining runs on arrays 1/32 the tile's size."""
+    q = len(words)
+    w, bm = words[0].shape
+    f = 2 if q <= 2 else 4 if q <= 4 else 8
+    per = 32 // f
+    fields = (jnp.arange(per, dtype=jnp.uint32) * f)[None, :, None]
+    chunks = []
+    for h in range(f):
+        comb = None
+        for i, x in enumerate(words):
+            c = (x >> (h * per)) & jnp.uint32((1 << per) - 1)
+            for s, m in _SPREAD[f]:
+                c = (c | (c << s)) & jnp.uint32(m)
+            comb = c if comb is None else comb | (c << i)
+        chunks.append((comb[:, None, :] >> fields) & jnp.uint32((1 << f) - 1))
+    codes = jnp.stack(chunks, axis=1)                 # (W, f, 32/f, bm)
+    return codes.reshape(w * 32, bm)[:bn].astype(jnp.int32)
 
 
 def row_block(rows: int) -> int:
@@ -78,12 +127,11 @@ def _pad_axis(x, mult, axis, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def code_dot(a_codes: jax.Array, plane: jax.Array) -> jax.Array:
-    """Exact integer (rows, bn)·(bn, bm) of codes against a 0/1 plane, as
-    a bf16 MXU dot with f32 accumulation (see the module docstring)."""
-    # Mosaic casts uint8 to a float only by way of int32
-    a = a_codes.astype(jnp.int32).astype(jnp.bfloat16)
-    d = jax.lax.dot(a, plane, preferred_element_type=jnp.float32)
+def code_dot(a: jax.Array, w: jax.Array) -> jax.Array:
+    """Exact int32 (rows, bn)·(bn, bm) of small integers (|a|, |w| ≤ 255,
+    partial sums below 2^24), as a bf16 MXU dot with f32 accumulation."""
+    d = jax.lax.dot(a.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32)
     return d.astype(jnp.int32)
 
 
@@ -94,9 +142,54 @@ def activation_bits(a_codes: jax.Array, p: int) -> list:
     return [((a >> k) & 1).astype(jnp.int8) for k in range(p)]
 
 
-def dots_per_tile(q: int, p: int, fidelity: str = "code") -> int:
-    """MXU dot issues per (m, n) grid cell — the §V-D collapse, measurable."""
-    return q if fidelity == "code" else q * p
+def one_dot_exact(q: int, p: int, z_a: int, bn: int) -> bool:
+    """Whether one centred dot of (bn,) activation codes against q-bit
+    weight codes keeps every f32 partial sum below 2^24 (exact)."""
+    return bn * max(z_a, (1 << p) - 1 - z_a) * ((1 << q) - 1) < 1 << 24
+
+
+def dots_per_tile(q: int, p: int, fidelity: str = "code", *, bn: int,
+                  z_a: int) -> int:
+    """MXU dot issues per (m, n) grid cell — the §V-D collapse on both
+    operands, measurable: 1 within the exactness bound, else q; q·p for
+    the bit-serial oracle."""
+    if fidelity != "code":
+        return q * p
+    return 1 if one_dot_exact(q, p, z_a, bn) else q
+
+
+def tile_corr(a_codes, words: list, *, q: int, p: int, z_a, z_w, bn: int,
+              fidelity: str, one_dot: bool) -> jax.Array:
+    """The integer core of one (rows, bn) × (bn, bm) grid cell, shared by
+    the per-leaf and fused kernels: Σ_j (a_j − z_a)(w_j − z_w) as int32.
+
+    `words` holds the cell's q packed planes, (bn//32, bm) uint32 each;
+    `z_a`/`z_w` are Python ints or traced int32 scalars; `one_dot` is the
+    caller's static `one_dot_exact` (code fidelity only)."""
+    if fidelity == "code":
+        ac = a_codes.astype(jnp.int32) - z_a          # exact in bf16
+        if one_dot:
+            acc = code_dot(ac, _unpack_codes(words, bn))
+        else:
+            acc = code_dot(ac, _unpack_words(words[0], bn))
+            for i in range(1, q):
+                acc += (1 << i) * code_dot(ac, _unpack_words(words[i], bn))
+        return acc - z_w * jnp.sum(ac, axis=-1, keepdims=True)
+    # "bitserial": both operands decomposed, a^(k) AND W^(i) popcount-
+    # accumulated as int MXU matmuls, the zero points corrected after
+    planes = [_unpack_words(words[i], bn) for i in range(q)]
+    col_sum = jnp.zeros((1, planes[0].shape[1]), jnp.int32)  # Σ_j w[j, m]
+    for i in range(q):
+        col_sum += (1 << i) * jnp.sum(planes[i].astype(jnp.int32), axis=0,
+                                      keepdims=True)
+    a_bits = activation_bits(a_codes, p)
+    acc = jnp.zeros((a_codes.shape[0], planes[0].shape[1]), jnp.int32)
+    for i in range(q):
+        for k in range(p):
+            acc += (1 << (i + k)) * jax.lax.dot(
+                a_bits[k], planes[i], preferred_element_type=jnp.int32)
+    sum_a = jnp.sum(a_codes.astype(jnp.int32), axis=-1, keepdims=True)
+    return acc - z_a * col_sum - z_w * sum_a + bn * z_a * z_w
 
 
 # ---------------------------------------------------------------------------
@@ -164,45 +257,22 @@ def _leaf_call(body, a, planes, scale_tiles, *, q: int, bn: int, bm: int,
 
 
 # ---------------------------------------------------------------------------
-# bit-serial kernel: both operands decomposed to planes — the exact integer
-# computation MVDRAM performs in DRAM (AND + weighted popcount-accumulate).
-# fidelity="code" collapses the activation planes back into codes (§V-D
-# linearity): q int dots per tile instead of q·p, identical integers.
+# integer kernel: activation codes × packed weight planes, exact int32 per
+# tile (`tile_corr`), scaled into the f32 output tile by tile.
 # ---------------------------------------------------------------------------
 
 def _gemv_bs_kernel(a_ref, planes_ref, scale_ref, out_ref, *, q: int, p: int,
-                    z_a: int, z_w: int, bn: int, fidelity: str):
+                    z_a: int, z_w: int, bn: int, fidelity: str,
+                    one_dot: bool):
     n_idx = pl.program_id(2)
 
     @pl.when(n_idx == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a_codes = a_ref[...]                                  # (B, bn) uint8 codes
-    b = a_codes.shape[0]
-    bm = out_ref.shape[1]
-    # hoisted out of the (i, k) loops: each weight plane unpacked ONCE
-    planes = [_unpack_words(planes_ref[i], bn) for i in range(q)]
-    col_sum = jnp.zeros((1, bm), jnp.int32)               # Σ_j w_u[j, m]
-    for i in range(q):
-        col_sum += (1 << i) * jnp.sum(planes[i].astype(jnp.int32), axis=0,
-                                      keepdims=True)
-    acc = jnp.zeros((b, bm), jnp.int32)
-    if fidelity == "code":
-        # Σ_k 2^k a^(k) = a_codes ⇒ one dot per weight plane (exact).
-        for i in range(q):
-            acc += (1 << i) * code_dot(a_codes,
-                                       planes[i].astype(jnp.bfloat16))
-    else:  # "bitserial": the fully decomposed q·p-dot schedule (oracle)
-        a_bits = activation_bits(a_codes, p)
-        for i in range(q):
-            for k in range(p):
-                # a^(k) AND W^(i), popcount-accumulated: an int MXU matmul.
-                partial = jax.lax.dot(a_bits[k], planes[i],
-                                      preferred_element_type=jnp.int32)
-                acc += (1 << (i + k)) * partial
-    sum_a = jnp.sum(a_codes.astype(jnp.int32), axis=-1, keepdims=True)
-    corr = acc - z_a * col_sum - z_w * sum_a + bn * z_a * z_w
+    corr = tile_corr(a_ref[...], [planes_ref[i] for i in range(q)], q=q,
+                     p=p, z_a=z_a, z_w=z_w, bn=bn, fidelity=fidelity,
+                     one_dot=one_dot)
     out_ref[...] += corr.astype(jnp.float32) * scale_ref[0]
 
 
@@ -210,18 +280,20 @@ def gemv_bs_pallas(a_codes, planes, scale_tiles, *, q: int, p: int,
                    z_a: int, z_w: int, bn: int, bm: int,
                    fidelity: str = "code", interpret: bool = False):
     """a_codes (B, N) uint8 (pad with z_a); planes (q, N//32, M) uint32."""
-    global LAUNCHES
+    global LAUNCHES, ONE_DOT_LAUNCHES
     if fidelity not in ("code", "bitserial"):
         raise ValueError(
             f"fidelity must be 'code' or 'bitserial', got {fidelity!r} "
             f"(a_codes shape {tuple(a_codes.shape)})")
+    one_dot = fidelity == "code" and one_dot_exact(q, p, z_a, bn)
     LAUNCHES += 1
+    ONE_DOT_LAUNCHES += one_dot
     b = a_codes.shape[0]
     br = row_block(b)
     a_codes = _pad_axis(a_codes, br, 0, value=z_a)
     out = _leaf_call(
         functools.partial(_gemv_bs_kernel, q=q, p=p, z_a=z_a, z_w=z_w,
-                          bn=bn, fidelity=fidelity),
+                          bn=bn, fidelity=fidelity, one_dot=one_dot),
         a_codes, planes, scale_tiles, q=q, bn=bn, bm=bm, br=br,
         interpret=interpret)
     return out[:b]

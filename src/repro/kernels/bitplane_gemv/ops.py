@@ -6,8 +6,9 @@ rows, activation quantization for the bit-serial mode, and backend dispatch
 `"jnp"` oracle — the jnp path READS THE SAME PACKED PLANES, so its HLO bytes
 reflect the packed-storage memory win and it is what multi-pod dry-runs
 lower). The bit-serial entry points take `fidelity`: "code" (default) issues
-q integer dots per tile via the §V-D linearity collapse, "bitserial" the
-fully decomposed q·p schedule — identical integers (see kernel.py).
+one integer dot per tile via the §V-D linearity collapse of both operands
+(one per weight plane past its exactness bound), "bitserial" the fully
+decomposed q·p schedule — identical integers (see kernel.py).
 """
 from __future__ import annotations
 
@@ -97,9 +98,10 @@ def bitplane_gemv_bitserial(a: jax.Array, bw: BitplaneWeights,
     """Quantize activations to p-bit codes, then integer bit-plane GeMV —
     the exact integer computation of the paper (§V + §VI combined).
 
-    `fidelity="code"` (default) uses the §V-D linearity collapse (q int dots
-    per tile); `fidelity="bitserial"` issues the fully decomposed q·p-dot
-    schedule. Identical integers either way (tested)."""
+    `fidelity="code"` (default) uses the §V-D linearity collapse (one int
+    dot per tile within `kernel.one_dot_exact`); `fidelity="bitserial"`
+    issues the fully decomposed q·p-dot schedule. Identical integers
+    either way (tested)."""
     aq = quantize_activations(a, a_spec)
     out = bitplane_gemv_codes(aq.values, bw, a_spec.bits, int(aq.zero),
                               impl=impl, bn=bn, bm=bm, fidelity=fidelity)

@@ -15,18 +15,27 @@ the program-wide (BN, BM) envelope with *exactness-preserving* values —
 
   * weight planes pad with 0 bits,
   * activation codes pad with the layer's zero point z_a,
-  * the epilogue's `+ BN·z_a·z_w` term uses the padded width BN,
 
-so the padded rows cancel algebraically: the extra `−z_w·(BN−bn)·z_a` from
-`sum_a` is exactly offset by the extra `+(BN−bn)·z_a·z_w`, the extra plane
-rows are zero so `acc` and `col_sum` are untouched, and every operation is
-integer-exact — the fused kernel is integer-identical (not just close) to
-the per-leaf path. Fully-padded grid steps (a layer with fewer reduction
-tiles than the envelope) carry zero scales, so whatever their finite
-integer correction is, they contribute exactly 0.0. Mixed weight/activation
-precisions ride the same trick: the plane loop runs to the envelope q_max
-with zero-padded planes, and the bitserial path's code loop to p_max —
-codes < 2^p_l have zero high bits, so the extra dots are exact zeros.
+so the padded rows cancel algebraically. The code body centres the codes
+(`ac = a − z_a`, see kernel.py): a padded row is 0 against zero weight
+bits, so it adds nothing to `ac · w` or to `z_w · Σ ac`. The bit-serial
+oracle keeps the uncentred epilogue with its `+ BN·z_a·z_w` term at the
+padded width BN: the extra `−z_w·(BN−bn)·z_a` from `sum_a` is exactly
+offset by the extra `+(BN−bn)·z_a·z_w`, and the extra plane rows leave
+`acc` and `col_sum` untouched. Every operation is integer-exact, so the
+fused kernel is integer-identical (not just close) to the per-leaf path.
+Fully-padded grid steps (a layer with fewer reduction tiles than the
+envelope) carry zero scales, so whatever their finite integer correction
+is, they contribute exactly 0.0. Mixed weight/activation precisions ride
+the same trick: the weight codes are built from the envelope's q_max
+planes, zero-padded above a layer's q, and the bitserial path's code loop
+runs to p_max — codes < 2^p_l have zero high bits, so the extra dots are
+exact zeros.
+
+One dot per cell: the code body folds the q_max planes into one weight
+code tile and issues one dot when `one_dot_exact` holds for the envelope
+(BN, q_max and the widest centred code of any member, all static in the
+plan — `ProgramKernelPlan.one_dot`); otherwise one dot per plane.
 
 Codes are stored once per LAYER, not per slot: a scalar-prefetched table
 maps each m-slot to its layer (and that layer's zero points, read from
@@ -34,7 +43,8 @@ SMEM), and the codes BlockSpec follows it. The activation-row axis is the
 outermost grid dimension, tiled like the per-leaf kernels (`row_block`).
 
 `LAUNCHES` counts `pallas_call` constructions at trace time — the parity
-test asserts the whole decode block costs ONE launch on this path.
+test asserts the whole decode block costs ONE launch on this path —
+and `ONE_DOT_LAUNCHES` those built with the one-dot body.
 """
 from __future__ import annotations
 
@@ -50,12 +60,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.quant import QuantSpec, quantize_activations
 from . import ops as bp_ops
-from .kernel import (_pad_axis, _unpack_words, activation_bits, code_dot,
-                     row_block)
+from .kernel import _pad_axis, one_dot_exact, row_block, tile_corr
 
 #: pallas_call constructions on the fused program path (trace-time; jit
 #: caching means one launch per distinct block shape, asserted in tests).
 LAUNCHES = 0
+#: of those, the launches built with the one-dot code body (trace-time)
+ONE_DOT_LAUNCHES = 0
 
 
 def static_zero(spec: QuantSpec) -> int:
@@ -99,6 +110,13 @@ class ProgramKernelPlan:
     @property
     def slots(self) -> int:
         return len(self.slot_layer)
+
+    @property
+    def one_dot(self) -> bool:
+        """Whether the code body's one dot per cell is exact for every
+        member at the envelope (BN, q_max)."""
+        return all(one_dot_exact(self.q_max, L.p, L.z_a, self.bn_max)
+                   for L in self.layers)
 
 
 @functools.lru_cache(maxsize=512)
@@ -219,7 +237,8 @@ def pack_params(plan: ProgramKernelPlan) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 def _program_kernel(params_ref, codes_ref, planes_ref, scale_ref, out_ref,
-                    *, q_max: int, p_max: int, bn: int, fidelity: str):
+                    *, q_max: int, p_max: int, bn: int, fidelity: str,
+                    one_dot: bool):
     slot = pl.program_id(1)
     nt = pl.program_id(2)
 
@@ -227,33 +246,13 @@ def _program_kernel(params_ref, codes_ref, planes_ref, scale_ref, out_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    z_a = params_ref[3 * slot + 1]
-    z_w = params_ref[3 * slot + 2]
-    a_codes = codes_ref[0, 0]                         # (br, BN) uint8
-    b = a_codes.shape[0]
-    bm = out_ref.shape[-1]
-    # every plane of the envelope unpacked exactly once per cell (planes of
-    # layers with q < q_max are zero-padded and their dots are exact zeros)
-    planes = [_unpack_words(planes_ref[0, 0, i], bn) for i in range(q_max)]
-    col_sum = jnp.zeros((1, bm), jnp.int32)
-    for i in range(q_max):
-        col_sum += (1 << i) * jnp.sum(planes[i].astype(jnp.int32), axis=0,
-                                      keepdims=True)
-    acc = jnp.zeros((b, bm), jnp.int32)
-    if fidelity == "code":
-        for i in range(q_max):
-            acc += (1 << i) * code_dot(a_codes,
-                                       planes[i].astype(jnp.bfloat16))
-    else:  # "bitserial" — codes < 2^p have zero high bits: exact zeros
-        a_bits = activation_bits(a_codes, p_max)
-        for i in range(q_max):
-            for k in range(p_max):
-                acc += (1 << (i + k)) * jax.lax.dot(
-                    a_bits[k], planes[i], preferred_element_type=jnp.int32)
-    sum_a = jnp.sum(a_codes.astype(jnp.int32), axis=-1, keepdims=True)
-    # bn here is the PADDED envelope BN — see the module docstring for why
-    # that keeps the correction exact for every ragged member tile
-    corr = acc - z_a * col_sum - z_w * sum_a + bn * z_a * z_w
+    # planes of layers with q < q_max are zero-padded; bn is the PADDED
+    # envelope BN — see the module docstring for why both stay exact
+    corr = tile_corr(codes_ref[0, 0],
+                     [planes_ref[0, 0, i] for i in range(q_max)], q=q_max,
+                     p=p_max, z_a=params_ref[3 * slot + 1],
+                     z_w=params_ref[3 * slot + 2], bn=bn, fidelity=fidelity,
+                     one_dot=one_dot)
     out_ref[0] += corr.astype(jnp.float32) * scale_ref[0, 0]
 
 
@@ -264,11 +263,13 @@ def program_gemv(plan: ProgramKernelPlan, codes_t, planes_t, scale_t,
     un-activation-scaled outputs, gathered per layer by `gather_outputs`.
     codes_t is (L, NT, R, BN) from `pack_codes`; params_t the `pack_params`
     table, scalar-prefetched into SMEM."""
-    global LAUNCHES
+    global LAUNCHES, ONE_DOT_LAUNCHES
     if fidelity not in ("code", "bitserial"):
         raise ValueError(
             f"fidelity must be 'code' or 'bitserial', got {fidelity!r}")
+    one_dot = fidelity == "code" and plan.one_dot
     LAUNCHES += 1
+    ONE_DOT_LAUNCHES += one_dot
     _l, nt_max, rows, bn = codes_t.shape
     br = row_block(rows)   # rows is already a multiple of it (pack_codes)
     s = plan.slots
@@ -291,7 +292,7 @@ def program_gemv(plan: ProgramKernelPlan, codes_t, planes_t, scale_t,
     return pl.pallas_call(
         functools.partial(_program_kernel, q_max=plan.q_max,
                           p_max=plan.p_max, bn=plan.bn_max,
-                          fidelity=fidelity),
+                          fidelity=fidelity, one_dot=one_dot),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, rows, bm), jnp.float32),
         compiler_params=pltpu.CompilerParams(

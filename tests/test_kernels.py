@@ -1,5 +1,7 @@
 """Pallas kernel sweeps: shapes × bits × batch × dtypes, interpret-mode
 kernel body vs the pure-jnp oracle and vs exact dequantized matmul."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,6 +14,15 @@ from repro.kernels.bitplane_gemv import ops as bp
 from repro.kernels.quant_matmul import ops as qm
 
 SHAPES = [(512, 256, 1), (384, 300, 3), (1000, 130, 2), (256, 512, 4)]
+
+
+def _pow2_scales(bw):
+    """`bw` with each scale rounded to a power of two: every product and
+    sum of the kernels' f32 epilogue is then exact, whatever order or
+    fused multiply-add the compiler picks, so outputs compare the integer
+    cores bit for bit."""
+    return dataclasses.replace(
+        bw, scale=jnp.exp2(jnp.round(jnp.log2(bw.scale))))
 
 
 @pytest.mark.parametrize("n,m,b", SHAPES)
@@ -50,8 +61,9 @@ def test_bitplane_bitserial_kernel_vs_integer_ref(rng, n, m, b, q, p):
 @pytest.mark.parametrize("n,m,b", SHAPES[:2])
 @pytest.mark.parametrize("q,p", [(2, 4), (4, 4), (3, 2)])
 def test_code_dot_fast_path_equals_bitserial(rng, n, m, b, q, p):
-    """Σ_k 2^k a^(k) = a_codes ⇒ the q-dot fast path and the decomposed
-    q·p-dot schedule produce identical integers; both match the jnp oracle."""
+    """Σ_k 2^k a^(k) = a_codes and Σ_i 2^i W^(i) = w_codes ⇒ the one-dot
+    code path and the decomposed q·p-dot schedule produce identical
+    integers; both match the jnp oracle."""
     from repro.kernels.bitplane_gemv.kernel import dots_per_tile
     w = jnp.asarray(rng.normal(size=(n, m)), jnp.float32)
     a = jnp.asarray(rng.normal(size=(b, n)), jnp.float32)
@@ -63,11 +75,90 @@ def test_code_dot_fast_path_equals_bitserial(rng, n, m, b, q, p):
     bits = bp.bitplane_gemv_bitserial(a, bw, spec, impl="pallas_interpret",
                                       fidelity="bitserial")
     scale = float(jnp.abs(ref).max()) + 1e-9
-    assert float(jnp.abs(code - bits).max()) / scale <= 1e-4
+    np.testing.assert_array_equal(np.asarray(code), np.asarray(bits))
     np.testing.assert_allclose(np.asarray(code), np.asarray(ref),
                                rtol=1e-4, atol=1e-4 * scale)
-    assert dots_per_tile(q, p, "code") == q
-    assert dots_per_tile(q, p, "bitserial") == q * p
+    bn, _bm = bp._pick_blocks(n, m, None, None)
+    z_a = spec.zero_point
+    assert dots_per_tile(q, p, "code", bn=bn, z_a=z_a) == 1
+    assert dots_per_tile(q, p, "bitserial", bn=bn, z_a=z_a) == q * p
+
+
+@pytest.mark.parametrize("n,m,b", SHAPES[1:3])
+# (1, 4) fills 2-bit fields with one plane; (5, 3) and (8, 4) 8-bit fields
+@pytest.mark.parametrize("q,p", [(2, 4), (4, 4), (3, 2), (1, 4), (5, 3),
+                                 (8, 4)])
+@pytest.mark.parametrize("path", ["leaf", "fused"])
+def test_one_dot_body_is_bit_identical(rng, n, m, b, q, p, path):
+    """The code body folds the q weight planes into one code tile and
+    issues one dot per cell; at ragged shapes its outputs EQUAL the
+    bit-serial oracle's and the jnp reference's (not to a tolerance), in
+    the per-leaf kernel and in the fused program kernel."""
+    from repro.kernels.bitplane_gemv import program as bp_prog
+    spec = QuantSpec(bits=p)
+    ws = [_pow2_scales(make_bitplane_weights(
+        jnp.asarray(rng.normal(size=(n, mm)), jnp.float32), QuantSpec(bits=q)))
+        for mm in (m, m + 70)]
+    a = jnp.asarray(rng.normal(size=(b, n)), jnp.float32)
+    refs = [bp.bitplane_gemv_bitserial(a, w, spec, impl="jnp") for w in ws]
+    for fidelity in ("code", "bitserial"):
+        if path == "leaf":
+            outs = [bp.bitplane_gemv_bitserial(a, w, spec,
+                                               impl="pallas_interpret",
+                                               fidelity=fidelity)
+                    for w in ws]
+        else:
+            plan = bp_prog.plan_from_weights(tuple(ws), spec)
+            assert plan.one_dot
+            outs = bp_prog.fused_group_linears(a, ws, p, fidelity=fidelity,
+                                               interpret=True)
+        for got, ref in zip(outs, refs):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_past_the_exactness_bound_takes_plane_dots(rng):
+    """8-bit weights against 8-bit codes at zero point 0: bn·255·255 is
+    past 2^24, so one f32-accumulated dot could round. Both kernels build
+    the per-plane body there (the one-dot counters stay put) and still
+    equal the jnp reference exactly."""
+    from repro.kernels.bitplane_gemv import kernel as bk
+    from repro.kernels.bitplane_gemv import program as bp_prog
+    n, m, b, q, p, z_a = 1000, 130, 2, 8, 8, 0
+    bw = _pow2_scales(make_bitplane_weights(
+        jnp.asarray(rng.normal(size=(n, m)), jnp.float32), QuantSpec(bits=q)))
+    codes = jnp.asarray(rng.integers(0, 256, size=(b, n)), jnp.uint8)
+    bn, bm = bp._pick_blocks(n, m, None, None)
+    assert not bk.one_dot_exact(q, p, z_a, bn)
+    assert bk.dots_per_tile(q, p, "code", bn=bn, z_a=z_a) == q
+    ref = np.asarray(bp.bitplane_gemv_codes(codes, bw, p, z_a, impl="jnp"))
+    for fidelity in ("code", "bitserial"):
+        got = bp.bitplane_gemv_codes(codes, bw, p, z_a,
+                                     impl="pallas_interpret",
+                                     fidelity=fidelity)
+        np.testing.assert_array_equal(np.asarray(got), ref)
+    # the per-leaf launch itself, built outside the jit cache
+    a2 = bk._pad_axis(codes, bn, 1, value=z_a)
+    planes = bk._pad_axis(bk._pad_axis(bw.planes, bn // 32, 1), bm, 2)
+    scale_t = bk._pad_axis(bp._expand_scales(bw, bn, a2.shape[1]), bm, 1)
+    l0, o0 = bk.LAUNCHES, bk.ONE_DOT_LAUNCHES
+    got = bk.gemv_bs_pallas(a2, planes, scale_t, q=q, p=p, z_a=z_a,
+                            z_w=bw.zero, bn=bn, bm=bm, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got)[:, :m], ref)
+    assert (bk.LAUNCHES - l0, bk.ONE_DOT_LAUNCHES - o0) == (1, 0)
+    # the fused program kernel
+    plan = bp_prog.build_plan(((n, m, q, 1, bw.zero, p, z_a),))
+    assert not plan.one_dot
+    planes_t, scale_t = bp_prog.pack_weights(plan, (bw,))
+    codes_t = bp_prog.pack_codes(plan, (codes,), bk.row_block(b))
+    params_t = jnp.asarray(bp_prog.pack_params(plan))
+    l0, o0 = bp_prog.LAUNCHES, bp_prog.ONE_DOT_LAUNCHES
+    for fidelity in ("code", "bitserial"):
+        out = bp_prog.program_gemv(plan, codes_t, planes_t, scale_t,
+                                   params_t, fidelity=fidelity,
+                                   interpret=True)
+        got = bp_prog.gather_outputs(plan, out, b)[0]
+        np.testing.assert_array_equal(np.asarray(got), ref)
+    assert (bp_prog.LAUNCHES - l0, bp_prog.ONE_DOT_LAUNCHES - o0) == (2, 0)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

@@ -250,3 +250,55 @@ def test_run_kernel_needs_a_tpu_or_interpret(rng):
     _eng, _hs, prog, X = _build(BLOCKS[2], 2, rng)
     with pytest.raises(RuntimeError, match="PALLAS_INTERPRET"):
         prog.run_kernel(X)
+
+
+@pytest.mark.parametrize("config,leaf,fused", [
+    ("qwen2-7b-w2a4", 3, 2),        # o, down, lm_head; q/k/v, up/gate
+    ("starcoder2-3b-w4a4", 4, 1),   # o, up, down, lm_head; q/k/v
+])
+def test_served_launches_take_the_one_dot_body(config, leaf, fused):
+    """Trace only, nothing executed: one decode step of each benchmark
+    configuration at its full layer shapes, through the served path with
+    the kernels in interpret mode. Every per-leaf (`bitplane_gemv_codes`)
+    and fused (`_run_codes`) launch is built with the one-dot body, and a
+    decode block still costs the same launches (the layer scan's body is
+    traced once, the lm_head after it)."""
+    import json
+    import os
+    import sys
+
+    import jax
+
+    import repro.kernels.bitplane_gemv.kernel as leaf_kernel
+    from repro.models.model import Model, param_defs
+    from repro.serve.quantize import quantize_defs
+
+    bench_tests = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "tests")
+    if bench_tests not in sys.path:
+        sys.path.insert(0, bench_tests)
+    import _paths                 # the benchmark tests' loader
+    bench_run = _paths.bench_run()
+    with open(os.path.join(_paths.BENCH, "configs", f"{config}.json")) as f:
+        a = json.load(f)["as_run"]
+    cfg = bench_run.program_config(a)
+    lin = EngineLinear(MVDRAMEngine(), backend=backends.PALLAS_INTERPRET)
+    model = Model(cfg, act_bits=a["act_bits"], impl=lin)
+    params = quantize_defs(param_defs(cfg), a["weight_bits"])
+    lanes, max_seq = 4, 640
+    cache = jax.eval_shape(lambda: model.init_cache(lanes, max_seq))
+    inp = jax.ShapeDtypeStruct((lanes,), jnp.int32)
+    pos = jax.ShapeDtypeStruct((), jnp.int32)
+    bp.bitplane_gemv_codes.clear_cache()    # both wrappers trace afresh
+    bp_prog._run_codes_jit.clear_cache()
+    counters = (leaf_kernel.LAUNCHES, leaf_kernel.ONE_DOT_LAUNCHES,
+                bp_prog.LAUNCHES, bp_prog.ONE_DOT_LAUNCHES)
+    jax.make_jaxpr(model.decode_step)(params, cache, inp, pos)
+    after = (leaf_kernel.LAUNCHES, leaf_kernel.ONE_DOT_LAUNCHES,
+             bp_prog.LAUNCHES, bp_prog.ONE_DOT_LAUNCHES)
+    assert tuple(x - y for x, y in zip(after, counters)) == (
+        leaf, leaf, fused, fused)
+    # the benchmark's launches per step, which its kernel metrics divide by
+    counts = bench_run.counts
+    step = counts.kernel_calls(a, a["weight_bits"], a["act_bits"], lanes)
+    assert len(step) == a["layers"] * (leaf - 1 + fused) + 1

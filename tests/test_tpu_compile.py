@@ -55,12 +55,13 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("bits", [2, 4])
+@pytest.mark.parametrize("bits", [2, 4, 1, 3, 8])
 @pytest.mark.parametrize("rows", [4, 512], ids=["decode", "prefill_chunk"])
 def test_code_kernel_compiles(one_chip, bits, rows):
     """The per-leaf §V-D code kernel: a 4-lane decode step and a 512-row
     prefill chunk (the activation-row axis is tiled) on the d_ff-wide up
-    projection."""
+    projection. Widths 1, 3 and 8 build their code tiles from 2-, 4- and
+    8-bit fields."""
     _compile(lambda a, w: ops.bitplane_gemv_codes(a, w, ACT_BITS, Z_A,
                                                   impl="pallas"),
              _sds(one_chip, (rows, D_MODEL), jnp.uint8),

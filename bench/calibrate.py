@@ -56,7 +56,7 @@ def main(argv=None) -> int:
         gc.collect()
         t_ref = time.perf_counter()
         smp = run.sample(sv, cell, seed)
-        got = run.readings(smp, a, seed, controls(a, args.controls))
+        got = run.readings(smp, cell, seed, controls(a, args.controls))
         # each control, put in the program's place, through the run's own
         # comparison: it has to come out not correct
         for name, stats in got.items():

@@ -1,9 +1,10 @@
 """Everything a run reads about its cell, found by name.
 
 `BENCHMARK.json` names the cell's configuration and traffic mix; the
-configuration's file, `traffic/<traffic>.json`, `limits/<cell>.json` and
-`metrics/<metric>.py` are looked up from those names, so a cell, a mix or
-a metric is added by adding files.
+configuration's file, its block family `families/<family>.py` (named by
+the file's `"family"` key), `traffic/<traffic>.json`, `limits/<cell>.json`
+and `metrics/<metric>.py` are looked up from those names, so a cell, a
+mix, a metric or a model's architecture is added by adding files.
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    family: object          # the configuration's families/<family>.py
     traffic: dict
     limits: dict
     end_to_end: list        # metric entries this cell reports untraced
@@ -47,11 +49,35 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     moved = {m["name"] for m in e2e}
     per_layer = [m for m in spec["per_layer"]
                  if m["moves"] in moved and _reports(m, name)]
-    return Cell(name=name, chips=w["chips"],
-                config=_load(root / conf["file"]),
-                traffic=_load(BENCH / "traffic" / f"{w['traffic']}.json"),
-                limits=_load(BENCH / "limits" / f"{name}.json"),
+    config = _load(root / conf["file"])
+    bench = root / BENCH.name
+    return Cell(name=name, chips=w["chips"], config=config,
+                family=family(config.get("family"), root),
+                traffic=_load(bench / "traffic" / f"{w['traffic']}.json"),
+                limits=_load(bench / "limits" / f"{name}.json"),
                 end_to_end=e2e, per_layer=per_layer)
+
+
+_FAMILIES: dict = {}
+
+
+def family(name, root: pathlib.Path = ROOT):
+    """The module families/<name>.py under `root`'s benchmark directory: a
+    block family's program config, reference and counts. Loaded once per
+    path, so its jitted functions keep their compiled programs."""
+    where = root / BENCH.name / "families"
+    path = where / f"{name}.py"
+    if not isinstance(name, str) or not path.is_file():
+        have = sorted(p.stem for p in where.glob("*.py"))
+        raise KeyError(f"no block family {name!r} in {where} (have {have})")
+    path = path.resolve()
+    if path not in _FAMILIES:
+        mod_spec = importlib.util.spec_from_file_location(
+            "bench_family_" + name.replace(".", "_").replace("-", "_"), path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]
 
 
 def peaks(device_kind: str) -> dict:

@@ -14,7 +14,8 @@ set-up, one tick of every trip bucket the traffic uses, and `warm_ticks`
 ticks of the sessions' own traffic. Then the window runs for `--seconds`;
 nothing compiles inside it. After it closes the peak device memory is read,
 the program is freed, and a sample of the requests the window finished is
-checked against the plain reference (`harness/reference.py`).
+checked against the plain reference of the configuration's block family
+(`families/<family>.py`, built on `harness/reference.py`).
 
 `--trace 0` prints the cell's end-to-end metrics, `--trace 1` its per-layer
 metrics (each read by `metrics/<name>.py`) and a `breakdown`, from a
@@ -55,18 +56,10 @@ TRACE_SECONDS = 2.0
 
 
 def program_config(a: dict):
-    """The program's ModelConfig for a configuration's `as_run` block."""
-    from repro.configs import get_config
-    base = get_config(a["arch"])
-    attn = dataclasses.replace(
-        base.attn, num_heads=a["heads"], num_kv_heads=a["kv_heads"],
-        head_dim=a["head_dim"], rope_base=a["rope_theta"],
-        qkv_bias=a["qkv_bias"])
-    return dataclasses.replace(
-        base, num_layers=a["layers"], d_model=a["d_model"], d_ff=a["d_ff"],
-        vocab_size=a["vocab"], attn=attn, weight_bits=a["weight_bits"],
-        norm_type=a["norm"], ffn_type=a["ffn"],
-        tie_embeddings=a["tie_embeddings"], dtype=a["dtype"])
+    """The dense family's `program_config`, under the name through which
+    the program's own tests build a benchmark configuration. A run asks its
+    cell's family."""
+    return spec.family("dense").program_config(a)
 
 
 def _log(what: str, t_start: float) -> None:
@@ -108,7 +101,7 @@ def serve(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     from repro.serve.scheduler import ContinuousBatcher, Request
 
     a, mix = cell.config["as_run"], cell.traffic
-    cfg = program_config(a)
+    cfg = cell.family.program_config(a)
     params = init_quantized_params(param_defs(cfg), model_key(seed),
                                    a["weight_bits"])
     eng = ServeEngine(cfg, params, max_seq=mix["max_seq"],
@@ -242,19 +235,22 @@ def end_to_end(sv: Served, t0: float) -> dict:
             "prompt_tokens": prompt}
 
 
-def step_context(sv: Served, a: dict, mix: dict, peak: dict) -> dict:
-    """Roofline sums over the window's useful inner steps, and the least
-    time of one executed step's kernel launches (see harness/counts.py)."""
+def step_context(sv: Served, cell: spec.Cell, peak: dict) -> dict:
+    """Roofline sums over the window's useful inner steps, and for each
+    kernel set the launches of one executed step and their least time, as
+    the cell's block family counts them with the window's counters (see
+    harness/counts.py)."""
+    fam, a = cell.family, cell.config["as_run"]
     useful_s = 0.0
     for _, lanes in sv.ticks:
         for t in range(max((s for s, _, _ in lanes), default=0)):
             pos = [p0 + t for s, p0, _ in lanes if s > t]
-            useful_s += counts.step_roofline_s(a, a["weight_bits"], pos, peak)
-    calls = counts.kernel_calls(a, a["weight_bits"], a["act_bits"],
-                                mix["lanes"])
-    return {"step_roofline_s": useful_s, "launches_per_step": len(calls),
-            "kernel_step_s": counts.kernel_roofline_s(
-                a, a["weight_bits"], a["act_bits"], mix["lanes"], peak)}
+            useful_s += counts.step_roofline_s(fam, a, a["weight_bits"], pos,
+                                               peak, sv.counters)
+    return {"step_roofline_s": useful_s,
+            "kernels": counts.kernel_roofline_s(
+                fam, a, a["weight_bits"], a["act_bits"],
+                cell.traffic["lanes"], peak, sv.counters)}
 
 
 # ---------------------------------------------------------------------------
@@ -288,17 +284,19 @@ def sample(sv: Served, cell: spec.Cell, seed: int) -> dict:
             "requests": len(pick), "malformed": bad}
 
 
-def readings(smp: dict, a: dict, seed: int, controls: tuple = ()) -> dict:
+def readings(smp: dict, cell: spec.Cell, seed: int,
+             controls: tuple = ()) -> dict:
     """The reference's view of the served tokens and, for each control
     variant (weight_bits, act_bits), of the tokens that variant puts first
     at the same positions: gaps below the reference's best (raw and in
     units of the row's logit spread) and ranks (0 = the reference's own
-    first choice)."""
+    first choice). The reference is the cell's block family's."""
     import numpy as np
     from harness import reference
+    a = cell.config["as_run"]
     variants = ((a["weight_bits"], a["act_bits"]),) + tuple(controls)
-    logits = reference.forward_logits(a, seed, smp["tokens"], smp["lengths"],
-                                      smp["rows"], variants)
+    logits = cell.family.forward_logits(a, seed, smp["tokens"],
+                                        smp["lengths"], smp["rows"], variants)
 
     def stats(tokens):
         gap, z, rank = (np.asarray(x) for x in reference.token_gaps(
@@ -334,7 +332,7 @@ def check(sv: Served, cell: spec.Cell, seed: int) -> dict:
     """The served tokens of a seeded sample of finished requests against
     the reference (`judge`)."""
     smp = sample(sv, cell, seed)
-    got = readings(smp, cell.config["as_run"], seed)["program"]
+    got = readings(smp, cell, seed)["program"]
     verdict = judge(got, len(smp["served"]), smp["malformed"], cell.limits)
     info = {k: v for k, v in got.items() if k != "rank_mean"}
     info["requests_compared"] = smp["requests"]
@@ -409,8 +407,7 @@ def _context(cell: spec.Cell, sv: Served, kind: str, ev: dict) -> dict:
     its roofline sums, and the reduced trace."""
     peak = spec.peaks(kind)
     return {"counters": sv.counters, "window_s": sv.window_s,
-            "steps": step_context(sv, cell.config["as_run"], cell.traffic,
-                                  peak),
+            "steps": step_context(sv, cell, peak),
             "trace": trace_mod.reduce(ev)}
 
 

@@ -88,7 +88,7 @@ def _control(bits):
     from repro.core import backends
     sv = bench_run.serve(cell, 2**31 + 5, 1.0, False, backends.JNP, 0.0)
     smp = bench_run.sample(sv, cell, 2**31 + 5)
-    got = bench_run.readings(smp, cell.config["as_run"], 2**31 + 5, (bits,))
+    got = bench_run.readings(smp, cell, 2**31 + 5, (bits,))
     control = got["control w%d a%d" % bits]
     verdict = bench_run.judge(control, len(smp["served"]), 0, cell.limits)
     return got["program"]["rank_mean"], control["rank_mean"], verdict

@@ -156,8 +156,8 @@ def test_program_spans_leave_the_accepted_metrics_bit_identical():
     assert k > 5
     with_spans = dict(ev, host=ev["host"] + spans)
     counters = {"occupancy_ticks": {4: 120, 3: 20}, "lanes": 4}
-    steps = {"step_roofline_s": 0.002, "launches_per_step": 113,
-             "kernel_step_s": 0.0021}
+    steps = {"step_roofline_s": 0.002, "kernels": {"bitplane_gemv": {
+        "launches_per_step": 113, "kernel_step_s": 0.0021}}}
     read = {}
     for name, e in (("plain", ev), ("spans", with_spans)):
         ctx = {"counters": counters, "window_s": 0.035, "steps": steps,

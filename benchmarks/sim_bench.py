@@ -585,8 +585,8 @@ def kernel_dots_issued(emit):
     a = jnp.asarray(rng.normal(size=(4, N)), jnp.float32)
     bw = make_bitplane_weights(w, QuantSpec(bits=Q))
     spec = QuantSpec(bits=P)
-    bn, _bm = bp._pick_blocks(N, M, None, None)
-    tile = dict(bn=bn, z_a=spec.zero_point)
+    tn = bp._pick_blocks(N, M, q=Q, rows=4)[0]
+    tile = dict(tn=tn, z_a=spec.zero_point)
     emit("kernel.bitserial_dots_per_tile",
          dots_per_tile(Q, P, "bitserial", **tile))
     emit("kernel.code_dots_per_tile", dots_per_tile(Q, P, "code", **tile),

@@ -4,14 +4,19 @@
 The simulator has executed the fused cross-layer wave schedule since PR 5,
 but the jit path still dispatched every decode-time linear as its own
 `bitplane_gemv_codes` launch. This module walks the SAME program structure
-in ONE `pallas_call`: a 2-D grid over (m-slot, reduction-tile) where the
+in ONE `pallas_call`: a 2-D grid over (m-slot, reduction cell) where the
 m-slots enumerate every layer's output tiles in the program's concurrency-
 group order — q/k/v (and up/gate) interleave on consecutive slots exactly
-the way their tiles share boundary waves in the simulator's schedule.
+the way their tiles share boundary waves in the simulator's schedule. A
+cell walks k compute tiles in ascending order (`lax.fori_loop`), as the
+per-leaf kernel does; k is picked at launch from the rows
+(`ops.cell_tiles`), and a slot's BM is one output block that divides
+every member's m (`ops.output_block`), so no member pads past its
+128-column tile.
 
 Why one launch is legal across heterogeneous layers: each layer keeps ITS
-OWN blocking (bn_l, bm_l) from `_pick_blocks`, and tiles are padded up to
-the program-wide (BN, BM) envelope with *exactness-preserving* values —
+OWN compute tile tn_l from `_pick_blocks`, and tiles are padded up to
+the program-wide (TN, BM) envelope with *exactness-preserving* values —
 
   * weight planes pad with 0 bits,
   * activation codes pad with the layer's zero point z_a,
@@ -19,12 +24,12 @@ the program-wide (BN, BM) envelope with *exactness-preserving* values —
 so the padded rows cancel algebraically. The code body centres the codes
 (`ac = a − z_a`, see kernel.py): a padded row is 0 against zero weight
 bits, so it adds nothing to `ac · w` or to `z_w · Σ ac`. The bit-serial
-oracle keeps the uncentred epilogue with its `+ BN·z_a·z_w` term at the
-padded width BN: the extra `−z_w·(BN−bn)·z_a` from `sum_a` is exactly
-offset by the extra `+(BN−bn)·z_a·z_w`, and the extra plane rows leave
+oracle keeps the uncentred epilogue with its `+ TN·z_a·z_w` term at the
+padded width TN: the extra `−z_w·(TN−tn)·z_a` from `sum_a` is exactly
+offset by the extra `+(TN−tn)·z_a·z_w`, and the extra plane rows leave
 `acc` and `col_sum` untouched. Every operation is integer-exact, so the
 fused kernel is integer-identical (not just close) to the per-leaf path.
-Fully-padded grid steps (a layer with fewer reduction tiles than the
+Fully-padded tiles (a layer with fewer reduction tiles than the
 envelope) carry zero scales, so whatever their finite integer correction
 is, they contribute exactly 0.0. Mixed weight/activation precisions ride
 the same trick: the weight codes are built from the envelope's q_max
@@ -32,9 +37,9 @@ planes, zero-padded above a layer's q, and the bitserial path's code loop
 runs to p_max — codes < 2^p_l have zero high bits, so the extra dots are
 exact zeros.
 
-One dot per cell: the code body folds the q_max planes into one weight
+One dot per tile: the code body folds the q_max planes into one weight
 code tile and issues one dot when `one_dot_exact` holds for the envelope
-(BN, q_max and the widest centred code of any member, all static in the
+(TN, q_max and the widest centred code of any member, all static in the
 plan — `ProgramKernelPlan.one_dot`); otherwise one dot per plane.
 
 Codes are stored once per LAYER, not per slot: a scalar-prefetched table
@@ -44,7 +49,8 @@ outermost grid dimension, tiled like the per-leaf kernels (`row_block`).
 
 `LAUNCHES` counts `pallas_call` constructions at trace time — the parity
 test asserts the whole decode block costs ONE launch on this path —
-and `ONE_DOT_LAUNCHES` those built with the one-dot body.
+`ONE_DOT_LAUNCHES` those built with the one-dot body, and `GRID_CELLS`
+their grid cells.
 """
 from __future__ import annotations
 
@@ -60,13 +66,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.quant import QuantSpec, quantize_activations
 from . import ops as bp_ops
-from .kernel import _pad_axis, one_dot_exact, row_block, tile_corr
+from .kernel import (_pad_axis, compiler_params, one_dot_exact, row_block,
+                     tile_corr, vmem_bytes)
 
 #: pallas_call constructions on the fused program path (trace-time; jit
 #: caching means one launch per distinct block shape, asserted in tests).
 LAUNCHES = 0
 #: of those, the launches built with the one-dot code body (trace-time)
 ONE_DOT_LAUNCHES = 0
+#: the grid cells of those launches, summed over their grids (trace-time)
+GRID_CELLS = 0
 
 
 def static_zero(spec: QuantSpec) -> int:
@@ -85,10 +94,9 @@ class LayerTiles:
     z_w: int        # weight zero point
     p: int          # activation bits
     z_a: int        # activation zero point
-    bn: int         # this layer's own reduction block
-    bm: int         # this layer's own output block
+    tn: int         # this layer's own compute tile (reduction rows)
     n_tiles: int
-    m_tiles: int
+    m_tiles: int    # output blocks of the plan's BM
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,9 +109,9 @@ class ProgramKernelPlan:
     groups: tuple                # concurrency groups, indices into layers
     slot_layer: tuple            # (S,) layer index per m-slot
     slot_mtile: tuple            # (S,) that layer's m-tile index
-    bn_max: int                  # padded reduction-block envelope BN
-    bm_max: int                  # padded output-block envelope BM
-    nt_max: int                  # reduction grid steps NT
+    tn: int                      # padded compute-tile envelope TN
+    bm: int                      # output block BM, dividing every m
+    nt: int                      # compute tiles NT along the reduction
     q_max: int
     p_max: int
 
@@ -113,9 +121,9 @@ class ProgramKernelPlan:
 
     @property
     def one_dot(self) -> bool:
-        """Whether the code body's one dot per cell is exact for every
-        member at the envelope (BN, q_max)."""
-        return all(one_dot_exact(self.q_max, L.p, L.z_a, self.bn_max)
+        """Whether the code body's one dot per tile is exact for every
+        member at the envelope (TN, q_max)."""
+        return all(one_dot_exact(self.q_max, L.p, L.z_a, self.tn)
                    for L in self.layers)
 
 
@@ -125,13 +133,14 @@ def build_plan(metas: tuple, groups: Optional[tuple] = None
     """metas: tuple of (n, m, q, g, z_w, p, z_a) per layer. Slots walk the
     concurrency groups in order, round-robin across each group's members —
     the kernel-grid mirror of the schedule's shared boundary waves."""
+    bm = bp_ops.output_block(tuple(meta[1] for meta in metas))
     layers = []
     for n, m, q, g, z_w, p, z_a in metas:
-        bn, bm = bp_ops._pick_blocks(n, m, None, None,
-                                     n // g if g > 1 else None)
+        tn = bp_ops._pick_blocks(n, m, None, None,
+                                 n // g if g > 1 else None)[0]
         layers.append(LayerTiles(
-            n=n, m=m, q=q, g=g, z_w=z_w, p=p, z_a=z_a, bn=bn, bm=bm,
-            n_tiles=-(-n // bn), m_tiles=-(-m // bm)))
+            n=n, m=m, q=q, g=g, z_w=z_w, p=p, z_a=z_a, tn=tn,
+            n_tiles=-(-n // tn), m_tiles=-(-m // bm)))
     if groups is None:
         groups = tuple((i,) for i in range(len(layers)))
     slot_layer, slot_mtile = [], []
@@ -144,8 +153,8 @@ def build_plan(metas: tuple, groups: Optional[tuple] = None
     return ProgramKernelPlan(
         layers=tuple(layers), groups=tuple(tuple(g) for g in groups),
         slot_layer=tuple(slot_layer), slot_mtile=tuple(slot_mtile),
-        bn_max=max(L.bn for L in layers), bm_max=max(L.bm for L in layers),
-        nt_max=max(L.n_tiles for L in layers),
+        tn=max(L.tn for L in layers), bm=bm,
+        nt=max(L.n_tiles for L in layers),
         q_max=max(L.q for L in layers), p_max=max(L.p for L in layers))
 
 
@@ -159,40 +168,37 @@ def plan_from_weights(ws: Sequence, a_spec: QuantSpec,
 
 
 # ---------------------------------------------------------------------------
-# slot-major packing: every (slot, nt) grid cell gets a fixed-size block so
-# all BlockSpec index maps stay static (TPU- and interpret-safe)
+# slot-major packing: every (slot, tile) gets a fixed-size block so all
+# BlockSpec index maps stay static (TPU- and interpret-safe)
 # ---------------------------------------------------------------------------
 
 def pack_weights(plan: ProgramKernelPlan, leaves: Sequence):
-    """leaves[l]: BitplaneWeights → planes_t (S, NT, q_max, BN//32, BM)
+    """leaves[l]: BitplaneWeights → planes_t (S, NT, q_max, TN//32, BM)
     uint32 and scale_t (S, NT, 1, BM) f32. Pad bits/scales are zero; scale
     rows past a layer's true reduction length are zeroed by
-    `_expand_scales`, so padded cells contribute nothing."""
-    wb = plan.bn_max // 32
+    `_expand_scales`, so padded tiles contribute nothing."""
+    wb, bm = plan.tn // 32, plan.bm
     per_layer = []
     for L, bw in zip(plan.layers, leaves):
-        wl = L.bn // 32
+        wl = L.tn // 32
         planes = bp_ops._pad_axis(bw.planes, wl, 1)
-        planes = bp_ops._pad_axis(planes, L.bm, 2)
+        planes = bp_ops._pad_axis(planes, bm, 2)
         scale = bp_ops._pad_axis(
-            bp_ops._expand_scales(bw, L.bn, L.n_tiles * L.bn), L.bm, 1)
+            bp_ops._expand_scales(bw, L.tn, L.n_tiles * L.tn), bm, 1)
         per_layer.append((planes, scale, wl))
     p_rows, s_rows = [], []
-    zero_p = jnp.zeros((plan.q_max, wb, plan.bm_max), jnp.uint32)
-    zero_s = jnp.zeros((1, plan.bm_max), jnp.float32)
+    zero_p = jnp.zeros((plan.q_max, wb, bm), jnp.uint32)
+    zero_s = jnp.zeros((1, bm), jnp.float32)
     for l, r in zip(plan.slot_layer, plan.slot_mtile):
         L = plan.layers[l]
         planes, scale, wl = per_layer[l]
         p_tiles, s_tiles = [], []
-        for nt in range(plan.nt_max):
+        for nt in range(plan.nt):
             if nt < L.n_tiles:
-                blk = planes[:, nt * wl:(nt + 1) * wl,
-                             r * L.bm:(r + 1) * L.bm]
-                blk = jnp.pad(blk, ((0, plan.q_max - L.q),
-                                    (0, wb - wl),
-                                    (0, plan.bm_max - L.bm)))
-                srow = scale[nt, r * L.bm:(r + 1) * L.bm][None, :]
-                srow = jnp.pad(srow, ((0, 0), (0, plan.bm_max - L.bm)))
+                blk = planes[:, nt * wl:(nt + 1) * wl, r * bm:(r + 1) * bm]
+                blk = jnp.pad(blk, ((0, plan.q_max - L.q), (0, wb - wl),
+                                    (0, 0)))
+                srow = scale[nt, r * bm:(r + 1) * bm][None, :]
             else:
                 blk, srow = zero_p, zero_s
             p_tiles.append(blk)
@@ -204,19 +210,19 @@ def pack_weights(plan: ProgramKernelPlan, leaves: Sequence):
 
 def pack_codes(plan: ProgramKernelPlan, codes: Sequence[jax.Array],
                br: int):
-    """codes[l]: (B, n_l) uint8 → (L, NT, R, BN) with R = B padded to a
+    """codes[l]: (B, n_l) uint8 → (L, NT, R, TN) with R = B padded to a
     multiple of the row block `br`; padded with each layer's z_a inside its
-    live tiles and with 0 on fully-padded grid steps."""
+    live tiles and with 0 on fully-padded tiles."""
     per_layer = []
     for L, c in zip(plan.layers, codes):
-        c = _pad_axis(_pad_axis(c, L.bn, 1, value=L.z_a), br, 0)
+        c = _pad_axis(_pad_axis(c, L.tn, 1, value=L.z_a), br, 0)
         tiles = [
-            jnp.pad(c[:, nt * L.bn:(nt + 1) * L.bn],
-                    ((0, 0), (0, plan.bn_max - L.bn)),
+            jnp.pad(c[:, nt * L.tn:(nt + 1) * L.tn],
+                    ((0, 0), (0, plan.tn - L.tn)),
                     constant_values=L.z_a)
             if nt < L.n_tiles else
-            jnp.zeros((c.shape[0], plan.bn_max), jnp.uint8)
-            for nt in range(plan.nt_max)]
+            jnp.zeros((c.shape[0], plan.tn), jnp.uint8)
+            for nt in range(plan.nt)]
         per_layer.append(jnp.stack(tiles))       # (NT, R, BN)
     return jnp.stack(per_layer)
 
@@ -233,27 +239,31 @@ def pack_params(plan: ProgramKernelPlan) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# the fused kernel body — one grid cell per (m-slot, reduction tile)
+# the fused kernel body — one grid cell per (m-slot, reduction cell), k
+# compute tiles a cell
 # ---------------------------------------------------------------------------
 
 def _program_kernel(params_ref, codes_ref, planes_ref, scale_ref, out_ref,
-                    *, q_max: int, p_max: int, bn: int, fidelity: str,
+                    *, q_max: int, p_max: int, tn: int, fidelity: str,
                     one_dot: bool):
     slot = pl.program_id(1)
-    nt = pl.program_id(2)
 
-    @pl.when(nt == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    # planes of layers with q < q_max are zero-padded; bn is the PADDED
-    # envelope BN — see the module docstring for why both stay exact
-    corr = tile_corr(codes_ref[0, 0],
-                     [planes_ref[0, 0, i] for i in range(q_max)], q=q_max,
-                     p=p_max, z_a=params_ref[3 * slot + 1],
-                     z_w=params_ref[3 * slot + 2], bn=bn, fidelity=fidelity,
-                     one_dot=one_dot)
-    out_ref[0] += corr.astype(jnp.float32) * scale_ref[0, 0]
+    z_a, z_w = params_ref[3 * slot + 1], params_ref[3 * slot + 2]
+
+    # planes of layers with q < q_max are zero-padded; tn is the PADDED
+    # envelope TN — see the module docstring for why both stay exact
+    def tile(t, acc):
+        corr = tile_corr(codes_ref[0, t],
+                         [planes_ref[0, t, i] for i in range(q_max)],
+                         q=q_max, p=p_max, z_a=z_a, z_w=z_w, tn=tn,
+                         fidelity=fidelity, one_dot=one_dot)
+        return acc + corr.astype(jnp.float32) * scale_ref[0, t]
+
+    out_ref[0] = jax.lax.fori_loop(0, codes_ref.shape[1], tile, out_ref[0])
 
 
 def program_gemv(plan: ProgramKernelPlan, codes_t, planes_t, scale_t,
@@ -261,44 +271,45 @@ def program_gemv(plan: ProgramKernelPlan, codes_t, planes_t, scale_t,
                  interpret: bool = False) -> jax.Array:
     """ONE pallas_call for the whole decode block → (S, R, BM) f32
     un-activation-scaled outputs, gathered per layer by `gather_outputs`.
-    codes_t is (L, NT, R, BN) from `pack_codes`; params_t the `pack_params`
-    table, scalar-prefetched into SMEM."""
-    global LAUNCHES, ONE_DOT_LAUNCHES
+    codes_t is (L, NT, R, TN) from `pack_codes`; params_t the `pack_params`
+    table, scalar-prefetched into SMEM. A grid cell walks k of the NT
+    compute tiles (`ops.cell_tiles`, from the rows)."""
+    global LAUNCHES, ONE_DOT_LAUNCHES, GRID_CELLS
     if fidelity not in ("code", "bitserial"):
         raise ValueError(
             f"fidelity must be 'code' or 'bitserial', got {fidelity!r}")
     one_dot = fidelity == "code" and plan.one_dot
-    LAUNCHES += 1
-    ONE_DOT_LAUNCHES += one_dot
-    _l, nt_max, rows, bn = codes_t.shape
+    _l, nt, rows, tn = codes_t.shape
     br = row_block(rows)   # rows is already a multiple of it (pack_codes)
-    s = plan.slots
-    wb = plan.bn_max // 32
-    bm = plan.bm_max
+    s, bm, q = plan.slots, plan.bm, plan.q_max
+    k = bp_ops.cell_tiles(nt, tn, q, bm, br)
+    grid = (rows // br, s, nt // k)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(rows // br, s, nt_max),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, br, bn),
+            pl.BlockSpec((1, k, br, tn),
                          lambda ri, si, ni, tbl: (tbl[3 * si], ni, ri, 0)),
-            pl.BlockSpec((1, 1, plan.q_max, wb, bm),
+            pl.BlockSpec((1, k, q, tn // 32, bm),
                          lambda ri, si, ni, tbl: (si, ni, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 1, bm),
+            pl.BlockSpec((1, k, 1, bm),
                          lambda ri, si, ni, tbl: (si, ni, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, br, bm),
                                lambda ri, si, ni, tbl: (si, ri, 0)),
     )
-    return pl.pallas_call(
-        functools.partial(_program_kernel, q_max=plan.q_max,
-                          p_max=plan.p_max, bn=plan.bn_max,
+    out = pl.pallas_call(
+        functools.partial(_program_kernel, q_max=q, p_max=plan.p_max, tn=tn,
                           fidelity=fidelity, one_dot=one_dot),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, rows, bm), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params(vmem_bytes(q, tn, k, bm, br)),
         interpret=interpret,
     )(params_t, codes_t, planes_t, scale_t)
+    LAUNCHES += 1
+    ONE_DOT_LAUNCHES += one_dot
+    GRID_CELLS += grid[0] * grid[1] * grid[2]
+    return out
 
 
 def gather_outputs(plan: ProgramKernelPlan, out: jax.Array, b: int) -> list:
@@ -309,7 +320,7 @@ def gather_outputs(plan: ProgramKernelPlan, out: jax.Array, b: int) -> list:
                in enumerate(zip(plan.slot_layer, plan.slot_mtile))}
     outs = []
     for l, L in enumerate(plan.layers):
-        parts = [out[slot_of[(l, r)], :b, :L.bm] for r in range(L.m_tiles)]
+        parts = [out[slot_of[(l, r)], :b] for r in range(L.m_tiles)]
         outs.append(jnp.concatenate(parts, axis=-1)[:, :L.m])
     return outs
 

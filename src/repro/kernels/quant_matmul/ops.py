@@ -50,7 +50,7 @@ def quant_matmul(a: jax.Array, wq: QuantizedTensor, *, impl: str = "jnp",
     n, m = wq.values.shape
     q = wq.spec.bits
     g = wq.scale.shape[0]
-    bn, bm = _pick_blocks(n, m, bn, bm, n // g if g > 1 else None)
+    _tn, bn, bm = _pick_blocks(n, m, bn, bm, n // g if g > 1 else None)
     per = 32 // q
     if bn % per != 0:
         raise ValueError(

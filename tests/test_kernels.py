@@ -78,10 +78,10 @@ def test_code_dot_fast_path_equals_bitserial(rng, n, m, b, q, p):
     np.testing.assert_array_equal(np.asarray(code), np.asarray(bits))
     np.testing.assert_allclose(np.asarray(code), np.asarray(ref),
                                rtol=1e-4, atol=1e-4 * scale)
-    bn, _bm = bp._pick_blocks(n, m, None, None)
+    tn = bp._pick_blocks(n, m, q=q, rows=b)[0]
     z_a = spec.zero_point
-    assert dots_per_tile(q, p, "code", bn=bn, z_a=z_a) == 1
-    assert dots_per_tile(q, p, "bitserial", bn=bn, z_a=z_a) == q * p
+    assert dots_per_tile(q, p, "code", tn=tn, z_a=z_a) == 1
+    assert dots_per_tile(q, p, "bitserial", tn=tn, z_a=z_a) == q * p
 
 
 @pytest.mark.parametrize("n,m,b", SHAPES[1:3])
@@ -117,19 +117,21 @@ def test_one_dot_body_is_bit_identical(rng, n, m, b, q, p, path):
 
 
 def test_past_the_exactness_bound_takes_plane_dots(rng):
-    """8-bit weights against 8-bit codes at zero point 0: bn·255·255 is
+    """8-bit weights against 8-bit codes at zero point 0: tn·255·255 is
     past 2^24, so one f32-accumulated dot could round. Both kernels build
     the per-plane body there (the one-dot counters stay put) and still
-    equal the jnp reference exactly."""
+    equal the jnp reference exactly, with the cell walking both of the
+    reduction's compute tiles."""
     from repro.kernels.bitplane_gemv import kernel as bk
     from repro.kernels.bitplane_gemv import program as bp_prog
     n, m, b, q, p, z_a = 1000, 130, 2, 8, 8, 0
     bw = _pow2_scales(make_bitplane_weights(
         jnp.asarray(rng.normal(size=(n, m)), jnp.float32), QuantSpec(bits=q)))
     codes = jnp.asarray(rng.integers(0, 256, size=(b, n)), jnp.uint8)
-    bn, bm = bp._pick_blocks(n, m, None, None)
-    assert not bk.one_dot_exact(q, p, z_a, bn)
-    assert bk.dots_per_tile(q, p, "code", bn=bn, z_a=z_a) == q
+    tn, bn, bm = bp._pick_blocks(n, m, q=q, rows=b)
+    assert (tn, bn, bm) == (512, 1024, 256)
+    assert not bk.one_dot_exact(q, p, z_a, tn)
+    assert bk.dots_per_tile(q, p, "code", tn=tn, z_a=z_a) == q
     ref = np.asarray(bp.bitplane_gemv_codes(codes, bw, p, z_a, impl="jnp"))
     for fidelity in ("code", "bitserial"):
         got = bp.bitplane_gemv_codes(codes, bw, p, z_a,
@@ -139,12 +141,13 @@ def test_past_the_exactness_bound_takes_plane_dots(rng):
     # the per-leaf launch itself, built outside the jit cache
     a2 = bk._pad_axis(codes, bn, 1, value=z_a)
     planes = bk._pad_axis(bk._pad_axis(bw.planes, bn // 32, 1), bm, 2)
-    scale_t = bk._pad_axis(bp._expand_scales(bw, bn, a2.shape[1]), bm, 1)
-    l0, o0 = bk.LAUNCHES, bk.ONE_DOT_LAUNCHES
+    scale_t = bk._pad_axis(bp._expand_scales(bw, tn, a2.shape[1]), bm, 1)
+    l0, o0, c0 = bk.LAUNCHES, bk.ONE_DOT_LAUNCHES, bk.GRID_CELLS
     got = bk.gemv_bs_pallas(a2, planes, scale_t, q=q, p=p, z_a=z_a,
-                            z_w=bw.zero, bn=bn, bm=bm, interpret=True)
+                            z_w=bw.zero, tn=tn, bn=bn, bm=bm, interpret=True)
     np.testing.assert_array_equal(np.asarray(got)[:, :m], ref)
-    assert (bk.LAUNCHES - l0, bk.ONE_DOT_LAUNCHES - o0) == (1, 0)
+    assert (bk.LAUNCHES - l0, bk.ONE_DOT_LAUNCHES - o0,
+            bk.GRID_CELLS - c0) == (1, 0, 1)
     # the fused program kernel
     plan = bp_prog.build_plan(((n, m, q, 1, bw.zero, p, z_a),))
     assert not plan.one_dot
@@ -159,6 +162,52 @@ def test_past_the_exactness_bound_takes_plane_dots(rng):
         got = bp_prog.gather_outputs(plan, out, b)[0]
         np.testing.assert_array_equal(np.asarray(got), ref)
     assert (bp_prog.LAUNCHES - l0, bp_prog.ONE_DOT_LAUNCHES - o0) == (2, 0)
+
+
+# (n, m, q, rows, scale groups): ragged n and m, the decode and a
+# multi-row launch, per-group scales at a 256-row tile and at 512
+CELLS = [(1000, 300, 2, 4, 1), (1600, 130, 4, 40, 1), (2048, 384, 2, 4, 8),
+         (2048, 640, 4, 40, 4)]
+
+
+@pytest.mark.parametrize("n,m,q,rows,g", CELLS)
+def test_cell_walk_equals_one_tile_cells(rng, n, m, q, rows, g):
+    """The picker's cell walks several compute tiles; its outputs EQUAL
+    (not to a tolerance, and with unrounded scales) the same launch with
+    one-tile cells and the 256-column output block — the blocks every
+    launch had before cells were sized from the shape — in the per-leaf
+    kernel; and the fused kernel, over members of different m with the
+    same walk, equals the per-leaf path. Fewer grid cells, same numbers."""
+    from repro.kernels.bitplane_gemv import kernel as bk
+    from repro.kernels.bitplane_gemv import program as bp_prog
+    spec = QuantSpec(bits=4)
+    ws = [make_bitplane_weights(
+        jnp.asarray(rng.normal(size=(n, mm)), jnp.float32),
+        QuantSpec(bits=q, group_size=n // g if g > 1 else -1))
+        for mm in (m, m + 256)]
+    a = jnp.asarray(rng.normal(size=(rows, n)), jnp.float32)
+    aq = quantize_activations(a, spec)
+    tn, bn, bm = bp._pick_blocks(n, m, None, None, n // g if g > 1 else None,
+                                 q=q, rows=rows)
+    assert bn > tn          # the cell walks more than one tile
+    for fidelity in ("code", "bitserial"):
+        c0 = bk.GRID_CELLS
+        got = bp.bitplane_gemv_codes(aq.values, ws[0], 4, aq.zero,
+                                     impl="pallas_interpret",
+                                     fidelity=fidelity)
+        c1 = bk.GRID_CELLS
+        one = bp.bitplane_gemv_codes(aq.values, ws[0], 4, aq.zero,
+                                     impl="pallas_interpret", bn=tn, bm=256,
+                                     fidelity=fidelity)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+        assert 0 < c1 - c0 < bk.GRID_CELLS - c1
+        fused = bp_prog.fused_group_linears(a, ws, 4, fidelity=fidelity,
+                                            interpret=True)
+        for w, out in zip(ws, fused):
+            ref = bp.bitplane_gemv_bitserial(a, w, spec,
+                                              impl="pallas_interpret",
+                                              fidelity=fidelity)
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

@@ -185,14 +185,19 @@ def test_fused_group_linears_and_dense_group(rng):
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_pick_blocks_pads_small_m_instead_of_shrinking():
+@pytest.mark.parametrize("q", [None, 2])
+def test_pick_blocks_pads_small_m_instead_of_shrinking(q):
     """m < 128 must keep bm at the 128 output block (callers slice
     out[:, :m]); shrinking bm to m used to hand Pallas a misaligned
-    grid."""
-    bn, bm = bp._pick_blocks(256, 40, None, None, None)
-    assert bm == 128
-    bn, bm = bp._pick_blocks(256, 300, None, None, None)
+    grid. The same holds for one-tile cells (no `q`), for cells sized from
+    the shape and for a fused group's common block; a ragged m pads only
+    to its 128-column tile there."""
+    tn, bn, bm = bp._pick_blocks(256, 40, None, None, None, q=q, rows=4)
+    assert (tn, bn, bm) == (256, 256, 128)
+    tn, bn, bm = bp._pick_blocks(256, 300, None, None, None, q=q, rows=4)
     assert bm % 128 == 0
+    if q:
+        assert bm == 384 and bp.output_block((40, 300)) == 128
 
 
 def test_value_errors_carry_shapes(rng):
@@ -204,7 +209,7 @@ def test_value_errors_carry_shapes(rng):
         gemv_bs_pallas(jnp.zeros((2, 64), jnp.uint8),
                        jnp.zeros((3, 2, 128), jnp.uint32),
                        jnp.zeros((1, 128), jnp.float32),
-                       q=3, p=2, z_a=0, z_w=0, bn=64, bm=128,
+                       q=3, p=2, z_a=0, z_w=0, tn=64, bn=64, bm=128,
                        fidelity="nope")
     with pytest.raises(ValueError, match="fidelity"):
         bp_prog.program_gemv(None, jnp.zeros((1, 1, 1, 32), jnp.uint8),
@@ -252,6 +257,70 @@ def test_run_kernel_needs_a_tpu_or_interpret(rng):
         prog.run_kernel(X)
 
 
+def _bench_config(config):
+    """bench/run.py as a module, and a benchmark configuration's `as_run`
+    block."""
+    import json
+    import os
+    import sys
+    bench_tests = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "tests")
+    if bench_tests not in sys.path:
+        sys.path.insert(0, bench_tests)
+    import _paths                 # the benchmark tests' loader
+    with open(os.path.join(_paths.BENCH, "configs", f"{config}.json")) as f:
+        return _paths.bench_run(), json.load(f)["as_run"]
+
+
+def _step_launches(bench_run, a, rows):
+    """[(members' (n, m), grid cells, scoped VMEM, padded output columns)]
+    of every bit-plane launch of one served step of `as_run` block `a` at
+    `rows` activation rows, from the block picker: a per-leaf launch's
+    `_pick_blocks`, a fused group's plan and `cell_tiles`. The shapes are
+    the benchmark's (`families/dense.py:linears`)."""
+    from repro.kernels.bitplane_gemv import kernel as bk
+    q, p = a["weight_bits"], a["act_bits"]
+    z_a = bp_prog.static_zero(QuantSpec(bits=p))
+    br = bk.row_block(rows)
+    groups = {}
+    for name, n, m, grp in bench_run.spec.family("dense").linears(a):
+        groups.setdefault(grp or name, []).append((n, m))
+    out = []
+    for members in groups.values():
+        if len(members) == 1:
+            (n, m), = members
+            tn, bn, bm = bp._pick_blocks(n, m, q=q, rows=rows)
+            k, nt, m_pad = bn // tn, -(-n // bn) * bn // tn, -(-m // bm) * bm
+            cells = (m_pad // bm) * (nt // k)
+        else:
+            plan = bp_prog.build_plan(
+                tuple((n, m, q, 1, 0, p, z_a) for n, m in members),
+                (tuple(range(len(members))),))
+            tn, bm, nt = plan.tn, plan.bm, plan.nt
+            k = bp.cell_tiles(nt, tn, q, bm, br)
+            cells = plan.slots * (nt // k)
+            m_pad = sum(L.m_tiles for L in plan.layers) * bm
+        out.append((members, cells, bk.vmem_bytes(q, tn, k, bm, br), m_pad))
+    return out
+
+
+@pytest.mark.parametrize("config,cells", [("qwen2-7b-w2a4", 3013),
+                                          ("starcoder2-3b-w4a4", 1596)])
+@pytest.mark.parametrize("rows", [4, 8], ids=["decode", "prefill"])
+def test_served_step_grid_cells(config, cells, rows):
+    """The block picker at every launch of one served step of each
+    benchmark configuration (4 decode lanes; 8 in the prefill cell): a
+    step takes 3,013 grid cells (qwen2-7b, 53,942 with one-tile cells of
+    512 × 256) and 1,596 (starcoder2-3b, 23,112), every launch's blocks
+    fit the default scoped VMEM, and no output is padded."""
+    from repro.kernels.bitplane_gemv import kernel as bk
+    launches = _step_launches(*_bench_config(config), rows)
+    assert sum(c for _, c, _, _ in launches) == cells
+    assert max(v for _, _, v, _ in launches) <= bk.SCOPED_VMEM
+    for members, _, _, m_pad in launches:
+        assert m_pad == sum(m for _, m in members)
+
+
 @pytest.mark.parametrize("config,leaf,fused", [
     ("qwen2-7b-w2a4", 3, 2),        # o, down, lm_head; q/k/v, up/gate
     ("starcoder2-3b-w4a4", 4, 1),   # o, up, down, lm_head; q/k/v
@@ -262,25 +331,15 @@ def test_served_launches_take_the_one_dot_body(config, leaf, fused):
     the kernels in interpret mode. Every per-leaf (`bitplane_gemv_codes`)
     and fused (`_run_codes`) launch is built with the one-dot body, and a
     decode block still costs the same launches (the layer scan's body is
-    traced once, the lm_head after it)."""
-    import json
-    import os
-    import sys
-
+    traced once, the lm_head after it). The `GRID_CELLS` counters read
+    the picker's cells of one layer and the lm_head."""
     import jax
 
     import repro.kernels.bitplane_gemv.kernel as leaf_kernel
     from repro.models.model import Model, param_defs
     from repro.serve.quantize import quantize_defs
 
-    bench_tests = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench", "tests")
-    if bench_tests not in sys.path:
-        sys.path.insert(0, bench_tests)
-    import _paths                 # the benchmark tests' loader
-    bench_run = _paths.bench_run()
-    with open(os.path.join(_paths.BENCH, "configs", f"{config}.json")) as f:
-        a = json.load(f)["as_run"]
+    bench_run, a = _bench_config(config)
     cfg = bench_run.program_config(a)
     lin = EngineLinear(MVDRAMEngine(), backend=backends.PALLAS_INTERPRET)
     model = Model(cfg, act_bits=a["act_bits"], impl=lin)
@@ -292,12 +351,17 @@ def test_served_launches_take_the_one_dot_body(config, leaf, fused):
     bp.bitplane_gemv_codes.clear_cache()    # both wrappers trace afresh
     bp_prog._run_codes_jit.clear_cache()
     counters = (leaf_kernel.LAUNCHES, leaf_kernel.ONE_DOT_LAUNCHES,
-                bp_prog.LAUNCHES, bp_prog.ONE_DOT_LAUNCHES)
+                bp_prog.LAUNCHES, bp_prog.ONE_DOT_LAUNCHES,
+                leaf_kernel.GRID_CELLS + bp_prog.GRID_CELLS)
     jax.make_jaxpr(model.decode_step)(params, cache, inp, pos)
     after = (leaf_kernel.LAUNCHES, leaf_kernel.ONE_DOT_LAUNCHES,
-             bp_prog.LAUNCHES, bp_prog.ONE_DOT_LAUNCHES)
+             bp_prog.LAUNCHES, bp_prog.ONE_DOT_LAUNCHES,
+             leaf_kernel.GRID_CELLS + bp_prog.GRID_CELLS)
+    launches = _step_launches(bench_run, a, lanes)
+    per_layer = len(launches) // a["layers"]
+    cells = sum(c for _, c, _, _ in launches[:per_layer] + launches[-1:])
     assert tuple(x - y for x, y in zip(after, counters)) == (
-        leaf, leaf, fused, fused)
+        leaf, leaf, fused, fused, cells)
     # the benchmark's launches per step, which its kernel metrics divide by
     counts = bench_run.counts
     step = counts.kernel_calls(a, a["weight_bits"], a["act_bits"], lanes)

@@ -85,3 +85,23 @@ def test_fused_group_compiles(one_chip, bits, widths):
     ws = tuple(_weights(one_chip, D_MODEL, m, bits) for m in widths)
     _compile(lambda x, ws: program.fused_group_linears(x, ws, ACT_BITS),
              _sds(one_chip, (4, D_MODEL), jnp.bfloat16), ws)
+
+
+@pytest.mark.parametrize("n,widths,bits", [
+    (18944, (3584,), 2),            # qwen2-7b down: a 2.4 MB cell
+    (12288, (3072,), 4),            # starcoder2-3b down: a 3.1 MB cell
+    (3584, (18944,) * 2, 2),        # qwen2-7b up/gate
+    (3072, (3072, 256, 256), 4),    # starcoder2-3b q/k/v: 256-wide cells
+], ids=["qwen2_down", "starcoder2_down", "qwen2_up_gate", "starcoder2_qkv"])
+def test_served_cells_compile(one_chip, n, widths, bits):
+    """The largest grid cells of the benchmark's configurations, each the
+    whole reduction by 512 (256) output columns, within the default scoped
+    VMEM that Mosaic enforces here."""
+    ws = tuple(_weights(one_chip, n, m, bits) for m in widths)
+    if len(ws) == 1:
+        _compile(lambda a, w: ops.bitplane_gemv_codes(a, w, ACT_BITS, Z_A,
+                                                      impl="pallas"),
+                 _sds(one_chip, (4, n), jnp.uint8), ws[0])
+    else:
+        _compile(lambda x, ws: program.fused_group_linears(x, ws, ACT_BITS),
+                 _sds(one_chip, (4, n), jnp.bfloat16), ws)
